@@ -111,6 +111,8 @@ def main() -> None:
     # recorded payloads should carry the continuous-profiling figures
     # (achieved GFLOP/s per solve); sessions check this env at build time
     os.environ.setdefault("REPRO_PROFILE", "1")
+    from repro.launch import compile_cache
+    compile_cache.enable()
     names = sys.argv[1:] or list(BENCHES)
     print("name,us_per_call,derived")
     failed = []

@@ -1,8 +1,7 @@
 """Paper Figure 3: parallel scalability of the IRLS iterations.
 
-This container has one core, so wall-clock strong scaling is not
-measurable; instead we report the two quantities that DRIVE Fig 3, both
-derived structurally:
+Wall-clock strong scaling needs several chips; on one device this reports
+the two quantities that DRIVE Fig 3, both derived structurally:
 
   (a) block-Jacobi WORK REDUCTION vs p — the paper's explanation for its
       superlinear speedups: total preconditioner flops drop as blocks
@@ -10,12 +9,14 @@ derived structurally:
       measured here by wall-clock of the single-host IRLS at varying
       n_blocks, and analytically from the block plans.
   (b) per-shard collective bytes vs p for the sharded halo solver (lower +
-      HLO-walk at p = 2/4/8 in subprocesses) — the communication curve that
-      bends the scaling at high p (paper: N-D grids stop scaling at 64).
+      HLO-walk at p = 2/4/8 over the process's own devices) — the
+      communication curve that bends the scaling at high p (paper: N-D
+      grids stop scaling at 64).
 
 ``run_sharded`` (repo-root ``BENCH_sharded.json``; CI gate via
 ``python -m benchmarks.scaling --smoke``) is the DISTRIBUTED ADAPTIVE
-trajectory: on multi-device CPU (forced host device count) it solves grid
+trajectory: over the process's devices (on the CPU, start Python with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``) it solves grid
 and random-regular families through ``MinCutSession(backend="sharded")``
 under the fixed vs the convergence-masked adaptive schedule, asserting
 equal cuts, recording the total-PCG-iteration reduction the early exit
@@ -25,11 +26,7 @@ PCG step over the fixed baseline.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
+import time
 
 import numpy as np
 
@@ -37,115 +34,99 @@ from repro.core import IRLSConfig, MinCutSession
 
 from .common import grid_instance, timer
 
-_SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
+
+def _mesh_of(p: int):
+    """A p-device mesh of this process's devices.  The benchmarks run in
+    one process, which holds the chip; for CPU emulation start Python with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``."""
+    import jax
+
+    from repro.distributed.collectives import flat_mesh
+
+    devices = jax.devices()
+    if len(devices) < p:
+        raise RuntimeError(f"needs {p} devices; this process has "
+                           f"{len(devices)}")
+    return flat_mesh(devices[:p])
 
 
 def _collective_bytes_at(p: int, side: int) -> dict:
-    code = textwrap.dedent(f"""
-        import json
-        from repro.graphs import generators as gen
-        from repro.core import IRLSConfig
-        from repro.distributed.solver import ShardedSolver
-        from repro.launch import hlo_analysis as ha
-        g = gen.grid_2d({side}, {side}, seed=11)
-        inst = gen.segmentation_instance(g, ({side}, {side}), seed=12)
-        s = ShardedSolver(inst, IRLSConfig(n_irls=5, pcg_max_iters=20),
-                          schedule="halo", precond_bs=32)
-        c = ha.analyze(s.lower().compile().as_text(), {p})
-        print(json.dumps({{"collective": c.collective_bytes,
-                           "flops": c.flops, "hbm": c.hbm_bytes}}))
-    """)
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={p}",
-               PYTHONPATH=_SRC)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env, timeout=900)
-    if r.returncode != 0:
-        return {"error": r.stderr[-500:]}
-    return json.loads(r.stdout.strip().splitlines()[-1])
+    from repro.distributed.solver import ShardedSolver
+    from repro.graphs import generators as gen
+    from repro.launch import hlo_analysis as ha
+
+    mesh = _mesh_of(p)
+    g = gen.grid_2d(side, side, seed=11)
+    inst = gen.segmentation_instance(g, (side, side), seed=12)
+    s = ShardedSolver(inst, IRLSConfig(n_irls=5, pcg_max_iters=20),
+                      mesh=mesh, schedule="halo", precond_bs=32)
+    c = ha.analyze(s.lower().compile().as_text(), p)
+    return {"collective": c.collective_bytes, "flops": c.flops,
+            "hbm": c.hbm_bytes}
 
 
 QUALITY_RTOL = 1e-3     # max rel. cut difference adaptive vs fixed sharded
 
 
 def _sharded_payload_at(p: int, side: int, n_reg: int, n_irls: int,
-                        pcg_iters: int, timeout: int = 1800) -> dict:
-    """Run the sharded fixed-vs-adaptive comparison in a subprocess with a
-    forced host device count (the parent's jax is already initialized with
-    one device)."""
-    code = textwrap.dedent(f"""
-        import json, time
-        import numpy as np
-        from repro.graphs import generators as gen
-        from repro.core import IRLSConfig, MinCutSession, Problem
-        from repro.distributed.solver import ShardedSolver
-        from repro.launch import hlo_analysis as ha
+                        pcg_iters: int) -> dict:
+    """The sharded fixed-vs-adaptive comparison on a p-device mesh."""
+    from repro.core import Problem
+    from repro.distributed.solver import ShardedSolver
+    from repro.graphs import generators as gen
+    from repro.launch import hlo_analysis as ha
 
-        T, K, P = {n_irls}, {pcg_iters}, {p}
-        fixed = IRLSConfig(n_irls=T, pcg_max_iters=K)
-        adapt = IRLSConfig(n_irls=T, pcg_max_iters=K,
-                           irls_tol=1e-3, adaptive_tol=True)
+    mesh = _mesh_of(p)
+    fixed = IRLSConfig(n_irls=n_irls, pcg_max_iters=pcg_iters)
+    adapt = IRLSConfig(n_irls=n_irls, pcg_max_iters=pcg_iters,
+                       irls_tol=1e-3, adaptive_tol=True)
 
-        g = gen.grid_2d({side}, {side}, seed=11)
-        fams = [("grid", gen.segmentation_instance(g, ({side}, {side}),
-                                                   seed=12)),
-                ("random_regular",
-                 gen.flow_improve_instance(gen.random_regular({n_reg}, 4,
-                                                              seed=13),
-                                           seed=14))]
-        rows, solves = [], 0
-        for name, inst in fams:
-            sess = MinCutSession(Problem.build(inst, n_blocks=P), fixed,
-                                 backend="sharded", precond_bs=32)
-            rf = sess.solve(cfg=fixed)          # first call pays compile
-            t0 = time.perf_counter(); rf = sess.solve(cfg=fixed)
-            tf = time.perf_counter() - t0
-            ra = sess.solve(cfg=adapt)
-            t0 = time.perf_counter(); ra = sess.solve(cfg=adapt)
-            ta = time.perf_counter() - t0
-            solves += 4
-            itf, ita = int(rf.pcg_iters.sum()), int(ra.pcg_iters.sum())
-            rel = (abs(ra.cut_value - rf.cut_value)
-                   / max(abs(rf.cut_value), 1e-30))
-            rows.append(dict(
-                family=name, n=int(inst.n), m=int(inst.graph.m),
-                cut_fixed=float(rf.cut_value), cut_adaptive=float(ra.cut_value),
-                cut_rel_diff=float(rel),
-                quality_ok=bool(rel <= {QUALITY_RTOL}),
-                pcg_iters_fixed=itf, pcg_iters_adaptive=ita,
-                iter_reduction=float(itf) / max(ita, 1),
-                converged_early=bool(int(ra.pcg_iters[-1]) == 0),
-                s_per_solve_fixed=tf, s_per_solve_adaptive=ta))
+    g = gen.grid_2d(side, side, seed=11)
+    fams = [("grid", gen.segmentation_instance(g, (side, side), seed=12)),
+            ("random_regular",
+             gen.flow_improve_instance(gen.random_regular(n_reg, 4, seed=13),
+                                       seed=14))]
+    rows, solves = [], 0
+    for name, inst in fams:
+        sess = MinCutSession(Problem.build(inst, n_blocks=p), fixed,
+                             backend="sharded", mesh=mesh, precond_bs=32)
+        rf = sess.solve(cfg=fixed)          # first call pays compile
+        t0 = time.perf_counter()
+        rf = sess.solve(cfg=fixed)
+        tf = time.perf_counter() - t0
+        ra = sess.solve(cfg=adapt)
+        t0 = time.perf_counter()
+        ra = sess.solve(cfg=adapt)
+        ta = time.perf_counter() - t0
+        solves += 4
+        itf, ita = int(rf.pcg_iters.sum()), int(ra.pcg_iters.sum())
+        rel = (abs(ra.cut_value - rf.cut_value)
+               / max(abs(rf.cut_value), 1e-30))
+        rows.append(dict(
+            family=name, n=int(inst.n), m=int(inst.graph.m),
+            cut_fixed=float(rf.cut_value), cut_adaptive=float(ra.cut_value),
+            cut_rel_diff=float(rel), quality_ok=bool(rel <= QUALITY_RTOL),
+            pcg_iters_fixed=itf, pcg_iters_adaptive=ita,
+            iter_reduction=float(itf) / max(ita, 1),
+            converged_early=bool(int(ra.pcg_iters[-1]) == 0),
+            s_per_solve_fixed=tf, s_per_solve_adaptive=ta))
 
-        # collectives per PCG step (depth-2 while bodies of the lowered
-        # HLO), fixed vs adaptive — must be IDENTICAL: the masked schedule
-        # rides the same reductions
-        small_f = IRLSConfig(n_irls=3, pcg_max_iters=8)
-        small_a = IRLSConfig(n_irls=3, pcg_max_iters=8,
-                             irls_tol=1e-3, adaptive_tol=True)
-        counts = {{}}
-        for tag, cfg in (("fixed", small_f), ("adaptive", small_a)):
-            s = ShardedSolver(fams[0][1], cfg, schedule="halo",
-                              precond_bs=32)
-            body_rows = ha.while_loop_collectives(
-                s.lower().compile().as_text())
-            counts[tag] = sorted(r["direct"] for r in body_rows
-                                 if r["depth"] >= 2)
-        print(json.dumps(dict(
-            families=rows, solves=solves,
-            pcg_step_collectives=counts,
-            zero_extra_collectives=bool(
-                counts["fixed"] == counts["adaptive"]))))
-    """)
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={p}",
-               PYTHONPATH=_SRC)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env, timeout=timeout)
-    if r.returncode != 0:
-        raise RuntimeError(f"sharded bench subprocess failed:\n"
-                           f"{r.stderr[-2000:]}")
-    return json.loads(r.stdout.strip().splitlines()[-1])
+    # collectives per PCG step (depth-2 while bodies of the lowered HLO),
+    # fixed vs adaptive — must be IDENTICAL: the masked schedule rides the
+    # same reductions
+    small_f = IRLSConfig(n_irls=3, pcg_max_iters=8)
+    small_a = IRLSConfig(n_irls=3, pcg_max_iters=8,
+                         irls_tol=1e-3, adaptive_tol=True)
+    counts = {}
+    for tag, cfg in (("fixed", small_f), ("adaptive", small_a)):
+        s = ShardedSolver(fams[0][1], cfg, mesh=mesh, schedule="halo",
+                          precond_bs=32)
+        body_rows = ha.while_loop_collectives(s.lower().compile().as_text())
+        counts[tag] = sorted(r["direct"] for r in body_rows
+                             if r["depth"] >= 2)
+    return dict(families=rows, solves=solves, pcg_step_collectives=counts,
+                zero_extra_collectives=bool(
+                    counts["fixed"] == counts["adaptive"]))
 
 
 def run_sharded(smoke: bool = False):

@@ -29,7 +29,7 @@ Three variants share the same update rule:
 The matvec and the preconditioner are passed as closures so the same code
 path serves the single-host (ELL / Pallas), the oracle (dense) and the
 sharded (shard_map collective) implementations.  The INNER PRODUCTS are
-closures too (``dot``/``dot2``): the default is a local ``jnp.vdot``, and
+closures too (``dot``/``dot2``): the default is a local ``vdot``, and
 the sharded solver passes cross-shard psum reductions
 (``distributed.collectives.psum_dots``) — so ``pcg_masked`` and
 ``pcg_fixed_iters`` ARE the distributed PCG, not templates for one.
@@ -56,12 +56,18 @@ class PCGResult(NamedTuple):
     history: jax.Array    # f[max_iters+1] residual norms (NaN-padded)
 
 
+def vdot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Full-float32 inner product: a TPU dot defaults to bf16 passes, which
+    would round CG's step lengths."""
+    return jnp.vdot(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _resolve_dots(dot, dot2):
     """Default inner products: local vdot; ``dot2`` from ``dot`` (two
     reductions — XLA fuses them locally; distributed callers supply a
     genuinely fused single-psum version)."""
     if dot is None:
-        dot = lambda a, b: jnp.vdot(a, b)
+        dot = vdot
     if dot2 is None:
         def dot2(r, z, _dot=dot):
             return _dot(r, z), _dot(r, r)
@@ -84,7 +90,7 @@ def pcg(matvec: Callable[[jax.Array], jax.Array],
         precond = lambda r: r
     x = jnp.zeros_like(b) if x0 is None else x0
 
-    bb = jnp.vdot(b, b)
+    bb = vdot(b, b)
     # guard: b == 0 ⇒ x = 0 is exact; avoid dividing by zero
     bb = jnp.where(bb > 0, bb, 1.0)
     tol2 = jnp.asarray(tol, b.dtype) ** 2 * bb
@@ -92,8 +98,8 @@ def pcg(matvec: Callable[[jax.Array], jax.Array],
     r = b - matvec(x)
     z = precond(r)
     p = z
-    rz = jnp.vdot(r, z)
-    rr = jnp.vdot(r, r)
+    rz = vdot(r, z)
+    rr = vdot(r, r)
 
     hist_len = max_iters + 1 if record_history else 1
     history = jnp.full((hist_len,), jnp.nan, dtype=b.dtype)
@@ -106,15 +112,15 @@ def pcg(matvec: Callable[[jax.Array], jax.Array],
     def body(state):
         x, r, p, rz, rr, it, hist = state
         Ap = matvec(p)
-        pAp = jnp.vdot(p, Ap)
+        pAp = vdot(p, Ap)
         alpha = rz / jnp.where(pAp != 0, pAp, 1.0)
         x = x + alpha * p
         r = r - alpha * Ap
         z = precond(r)
-        rz_new = jnp.vdot(r, z)
+        rz_new = vdot(r, z)
         beta = rz_new / jnp.where(rz != 0, rz, 1.0)
         p = z + beta * p
-        rr = jnp.vdot(r, r)
+        rr = vdot(r, r)
         it = it + 1
         if record_history:
             hist = hist.at[it].set(jnp.sqrt(rr / bb))

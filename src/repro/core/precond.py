@@ -169,7 +169,8 @@ def apply_block_jacobi(M: BlockJacobi, x: jax.Array) -> jax.Array:
     explicit inverse was formed — see kernels/ops.block_diag_matmul)."""
     xb = gather_blocks(M.plan, x)  # [p, bs]
     if M.inv is not None:
-        yb = jnp.einsum("pij,pj->pi", M.inv, xb)
+        yb = jnp.einsum("pij,pj->pi", M.inv, xb,
+                        precision=jax.lax.Precision.HIGHEST)
     else:
         yb = jax.scipy.linalg.cho_solve((M.chol, True), xb[..., None])[..., 0]
     return scatter_blocks(M.plan, yb)

@@ -1251,6 +1251,17 @@ class MinCutSession:
             self._profile_scanned(cfg, dtype, warm)
         return run
 
+    def scanned_program(self, cfg: Optional[IRLSConfig] = None):
+        """The jitted program a cold ``solve(backend="scanned", cfg=cfg)``
+        runs (the same cached object), and its device arguments for the
+        Problem's own weights: ``run.lower(*args).compile()`` inspects it
+        without executing it."""
+        cfg = cfg or self.cfg
+        dtype = jnp.dtype(cfg.dtype)
+        run = self._get_scanned(cfg, dtype, batched=False)
+        g = self.problem.device_graph(dtype)
+        return run, (g.c, g.c_s, g.c_t)
+
     def _solve_scanned(self, cfg, weights, timings, warm_from=None,
                        c_ell=None):
         prob = self.problem

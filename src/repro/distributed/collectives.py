@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core.pcg import vdot
+
 SOLVER_AXIS = "shard"
 
 
@@ -36,7 +38,7 @@ def psum_dots(axis: str = SOLVER_AXIS, local_dot=None):
     ``vdot(a·valid, b·valid)``); plain ``vdot`` when None.
     """
     if local_dot is None:
-        local_dot = lambda a, b: jnp.vdot(a, b)
+        local_dot = vdot
 
     def dot(a, b):
         return jax.lax.psum(local_dot(a, b), axis)
@@ -47,31 +49,6 @@ def psum_dots(axis: str = SOLVER_AXIS, local_dot=None):
         return rz_rr[0], rz_rr[1]
 
     return dot, dot2
-
-
-def shard_map(body, mesh: Mesh, in_specs, out_specs, axis_names=None):
-    """Version-portable ``shard_map``.
-
-    Newer JAX exposes ``jax.shard_map`` (with ``check_vma``/``axis_names``);
-    older releases only have ``jax.experimental.shard_map.shard_map`` (with
-    ``check_rep``/``auto``).  Replication checking is disabled in both — the
-    solver bodies mix replicated scalars and sharded arrays freely.
-    ``axis_names`` restricts manual mode to those axes (the pipeline's
-    pod-only shard_map); None means manual over the whole mesh.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": False}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {"check_rep": False}
-    if axis_names is not None:
-        # partial manual: leave the remaining mesh axes to the auto sharder
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
 
 
 def flat_mesh(devices=None) -> Mesh:

@@ -45,15 +45,15 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import adaptive as sched
 from repro.core import laplacian as lap
 from repro.core.irls import IRLSConfig, eps_schedule_array
-from repro.core.pcg import pcg_fixed_iters, pcg_masked
+from repro.core.pcg import pcg_fixed_iters, pcg_masked, vdot
 from repro.obs import trace
 from repro.obs.metrics import get_registry
-from .collectives import SOLVER_AXIS, flat_mesh, psum_dots, shard_map
+from .collectives import SOLVER_AXIS, flat_mesh, psum_dots
 from .spmv import (HaloPlan, build_halo_ell, build_halo_plan,
                    build_psum_plan, coo_reweight, halo_exchange,
                    halo_l1_local, make_ell_halo_matvec, make_halo_matvec,
@@ -393,7 +393,7 @@ class ShardedSolver:
                     c.dtype)
 
             def local_dot(a, b_):
-                return jnp.vdot(a * valid, b_ * valid)
+                return vdot(a * valid, b_ * valid)
 
             dot, dot2 = psum_dots(axis, local_dot)
 
@@ -561,9 +561,13 @@ class ShardedSolver:
             return v[None], rels, iters, nclamps
 
         n_in = n_base + (4 if fused else 0)
-        fn = shard_map(body, mesh=self.mesh,
-                       in_specs=(P(SOLVER_AXIS),) * n_in,
-                       out_specs=(P(SOLVER_AXIS), P(), P(), P()))
+        self._in_specs = (P(SOLVER_AXIS),) * n_in
+        # replication checking off: the body mixes replicated scalars and
+        # sharded arrays freely
+        fn = jax.shard_map(body, mesh=self.mesh,
+                           in_specs=self._in_specs,
+                           out_specs=(P(SOLVER_AXIS), P(), P(), P()),
+                           check_vma=False)
         self._raw_body = fn
         return jax.jit(fn)
 
@@ -677,10 +681,11 @@ class ShardedSolver:
                                                           eps_sched)
             return v, rels, iters, nclamps
 
-        fn = shard_map(body, mesh=self.mesh,
-                       in_specs=(P(SOLVER_AXIS), P(SOLVER_AXIS),
-                                 P(SOLVER_AXIS), P(), P()),
-                       out_specs=(P(), P(), P(), P()))
+        self._in_specs = (P(SOLVER_AXIS), P(SOLVER_AXIS), P(SOLVER_AXIS),
+                          P(), P())
+        fn = jax.shard_map(body, mesh=self.mesh, in_specs=self._in_specs,
+                           out_specs=(P(), P(), P(), P()),
+                           check_vma=False)
         return jax.jit(fn)
 
     # -- execution --------------------------------------------------------------
@@ -813,8 +818,10 @@ class ShardedSolver:
         """
         with trace.span("sharded.solve", schedule=self.schedule, p=self.p,
                         n=self.plan.n):
-            out, rels, iters, nclamps = self._fn(*[jnp.asarray(a)
-                                                   for a in self.arrays()])
+            # each shard's slice goes straight to its own device
+            args = [jax.device_put(a, NamedSharding(self.mesh, spec))
+                    for a, spec in zip(self.arrays(), self._in_specs)]
+            out, rels, iters, nclamps = self._fn(*args)
             out = np.asarray(out).reshape(-1)
             if self.schedule == "halo":
                 v = out[self.plan.perm]

@@ -53,15 +53,19 @@ def bfs_grow(g: EdgeList, frac: float = 0.5, seed: int = 0) -> np.ndarray:
     return np.asarray(out, dtype=np.int64)
 
 
-def _heavy_edge_matching(g: EdgeList, rng: np.random.Generator) -> np.ndarray:
-    """Greedy heavy-edge matching; returns coarse label per node."""
+def _heavy_edge_matching(g: EdgeList, rng: np.random.Generator,
+                         node_w: np.ndarray, max_w: float) -> np.ndarray:
+    """Greedy heavy-edge matching; returns coarse label per node.  A pair
+    whose merged weight would exceed ``max_w`` stays unmatched, so no
+    coarse node outgrows a part (the METIS vertex-weight cap)."""
     order = np.argsort(-np.asarray(g.weight, dtype=np.float64), kind="stable")
     matched = np.full(g.n, -1, dtype=np.int64)
     src = np.asarray(g.src)[order]
     dst = np.asarray(g.dst)[order]
     nxt = 0
     for u, v in zip(src, dst):
-        if matched[u] < 0 and matched[v] < 0:
+        if (matched[u] < 0 and matched[v] < 0
+                and node_w[u] + node_w[v] <= max_w):
             matched[u] = matched[v] = nxt
             nxt += 1
     for u in range(g.n):
@@ -95,15 +99,19 @@ def _initial_kway(g: EdgeList, node_w: np.ndarray, p: int,
     labels = np.full(g.n, -1, dtype=np.int64)
     remaining = set(range(g.n))
     for part in range(p - 1):
-        if not remaining:
-            break
-        start = int(rng.choice(list(remaining)))
         vol = 0.0
-        frontier = [start]
-        labels[start] = part
-        remaining.discard(start)
-        vol += node_w[start]
-        while frontier and vol < target:
+        frontier = []
+        while remaining and vol < target:
+            if not frontier:
+                # (re)seed: a region enclosed by earlier parts stops growing
+                # short of its target, and without a new seed every node it
+                # left behind would fall into the last part
+                start = int(rng.choice(list(remaining)))
+                frontier = [start]
+                labels[start] = part
+                remaining.discard(start)
+                vol += node_w[start]
+                continue
             nf = []
             for u in frontier:
                 for v in csr.indices[csr.indptr[u]:csr.indptr[u + 1]]:
@@ -176,9 +184,14 @@ def partition_kway(g: EdgeList, p: int, seed: int = 0,
 
     levels: List[Tuple[EdgeList, np.ndarray, np.ndarray]] = []  # (graph, node_w, labels->coarse)
     cur_g, cur_w = g, node_w
-    while cur_g.n > max(coarsen_to, 8 * p) and cur_g.m > 0:
-        match = _heavy_edge_matching(cur_g, rng)
-        if int(match.max()) + 1 >= cur_g.n:  # no progress
+    stop_at = max(coarsen_to, 8 * p)
+    max_w = 1.5 * float(node_w.sum()) / stop_at
+    while cur_g.n > stop_at and cur_g.m > 0:
+        match = _heavy_edge_matching(cur_g, rng, cur_w, max_w)
+        # stalled: on tree-like graphs (road networks) each level matches
+        # only a few star edges, and the levels would pile weight onto a
+        # few hubs instead of shrinking the graph
+        if int(match.max()) + 1 > 0.95 * cur_g.n:
             break
         levels.append((cur_g, cur_w, match))
         cur_g, cur_w = _contract(cur_g, match, cur_w)

@@ -14,10 +14,13 @@ XLA), then every PCG preconditioning step is
 like the paper's "symbolic factorization once, numeric refactor per
 iteration" argument.
 
-Tiling: grid over blocks; each step loads one (bs, bs) block + its (bs,)
-slice into VMEM and issues an MXU matvec.  bs is padded to a multiple of 128
-by ops.py so the matmul dims are hardware-aligned; typical bs = 128–512
-⇒ 64 KiB–1 MiB per block in f32, well inside VMEM.
+Tiling: grid over blocks; each step loads one (bs, bs) block and its
+(1, bs) slice of x into VMEM and issues an MXU matvec.  x travels as
+(P, 1, bs) so the block's last two dimensions equal the array's.  bs is
+padded to a multiple of 128 by ops.py so the matmul dims are
+hardware-aligned; typical bs = 128–512 ⇒ 64 KiB–1 MiB per block in f32,
+well inside VMEM.  The solver runs in float32, so the matmul asks for
+HIGHEST precision rather than the MXU's default bf16 passes.
 """
 from __future__ import annotations
 
@@ -29,12 +32,11 @@ from jax.experimental import pallas as pl
 
 
 def _block_diag_matvec_kernel(a_ref, x_ref, y_ref):
-    a = a_ref[...]                     # (1, bs, bs)
-    x = x_ref[...]                     # (1, bs)
-    # MXU matvec: contract as (bs, bs) @ (bs, 1) to keep a 2-D matmul shape
-    y = jnp.dot(a[0], x[0][:, None],
-                preferred_element_type=jnp.float32)
-    y_ref[...] = y[:, 0][None].astype(y_ref.dtype)
+    # y = a @ x as the row vector x @ aᵀ: (1, bs) · (bs, bs)ᵀ → (1, bs)
+    y = jax.lax.dot_general(x_ref[0], a_ref[0], (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    y_ref[0] = y.astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -46,14 +48,13 @@ def block_diag_matvec_pallas(blocks: jax.Array, x: jax.Array,
     """
     p, bs, bs2 = blocks.shape
     assert bs == bs2 and x.shape == (p, bs)
-    return pl.pallas_call(
+    vec = pl.BlockSpec((1, 1, bs), lambda i: (i, 0, 0))
+    y = pl.pallas_call(
         _block_diag_matvec_kernel,
         grid=(p,),
-        in_specs=[
-            pl.BlockSpec((1, bs, bs), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, bs), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bs), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((p, bs), x.dtype),
+        in_specs=[pl.BlockSpec((1, bs, bs), lambda i: (i, 0, 0)), vec],
+        out_specs=vec,
+        out_shape=jax.ShapeDtypeStruct((p, 1, bs), x.dtype),
         interpret=interpret,
-    )(blocks, x)
+    )(blocks, x.reshape(p, 1, bs))
+    return y.reshape(p, bs)

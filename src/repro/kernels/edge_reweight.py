@@ -5,7 +5,7 @@ Two generations of fusion live here:
 ``edge_reweight_pallas`` — one pass over the COO edge list computes, per
 edge,
 
-    z_e = c_e · (v[src_e] − v[dst_e])         (gather, subtract, scale)
+    z_e = c_e · Δv_e                           (scale the voltage drop)
     w_e = sqrt(z_e² + ε²)                      (smoothed ℓ1 weight)
     r_e = c_e² / w_e                           (reweighted conductance)
 
@@ -19,17 +19,20 @@ to the single-sweep kernel below.
 row-parallel sweep over the slot-major (ELL) edge data: reweight, the ELL
 value fill (vals = −r), the L̃ diagonal (lane reduction + terminal
 conductances) and the RHS (r_s) come out of a single read of
-``cols/c_ell/c_s/c_t/v``.  The edge→slot scatter happens once per SOLVE
+``vn/c_ell/c_s/c_t/v``.  The edge→slot scatter happens once per SOLVE
 (core/laplacian.ell_edge_weights stages c into ``c_ell``); per iteration
 there is no scatter at all — each undirected edge is evaluated once per
 direction (z² is symmetric, both copies agree), trading ≤2× redundant FLOPs
-for a race-free, perfectly regular (R, k) tile that maps onto the VPU's
-8×128 lane grid.  Replaces four separate passes (reweight, fill_ell, diag
-segment_sum, rhs) of the unfused path.
+for a race-free, perfectly regular (R, k) tile.  Replaces four separate
+passes (reweight, fill_ell, diag segment_sum, rhs) of the unfused path.
+
+Neither kernel gathers: Mosaic lowers no row gather from an n-long vector,
+so the ops.py wrappers gather the voltages in XLA (``Δv = v[src] − v[dst]``
+per edge, ``vn = v[cols]`` per ELL slot) and the kernels stream fixed tiles.
+ε is a scalar in SMEM.
 
 Tiling: ``edge_reweight`` grids over edge blocks (E = 4096 edges per step);
-``fused_ell_sweep`` grids over row blocks (R = 512 rows, like ell_spmv).
-``v`` stays fully VMEM-resident in both (sharded upstream).
+``fused_ell_sweep`` grids over row blocks (R = 1024 rows, like ell_spmv).
 """
 from __future__ import annotations
 
@@ -38,60 +41,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ell_spmv import ROWS_PER_BLOCK   # fused sweep shares the SpMV row tile
 
 EDGES_PER_BLOCK = 4096
 
 
-def _edge_reweight_kernel(src_ref, dst_ref, c_ref, v_ref, eps_ref, r_ref):
-    src = src_ref[...]
-    dst = dst_ref[...]
-    c = c_ref[...]
-    v = v_ref[...]
+def _smem_scalar(eps, dtype) -> jax.Array:
+    return jnp.reshape(jnp.asarray(eps, dtype), (1,))
+
+
+def _edge_reweight_kernel(eps_ref, dv_ref, c_ref, r_ref):
     eps = eps_ref[0]
-    z = c * (jnp.take(v, src, axis=0, fill_value=0)
-             - jnp.take(v, dst, axis=0, fill_value=0))
+    c = c_ref[...]
+    z = c * dv_ref[...]
     r_ref[...] = (c * c) * jax.lax.rsqrt(z * z + eps * eps)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def edge_reweight_pallas(src: jax.Array, dst: jax.Array, c: jax.Array,
-                         v: jax.Array, eps: jax.Array,
+def edge_reweight_pallas(dv: jax.Array, c: jax.Array, eps: jax.Array,
                          *, interpret: bool = False) -> jax.Array:
-    """r_e = c² / sqrt((c·Δv)² + ε²)  (see ref.edge_reweight_ref).
+    """r_e = c² / sqrt((c·Δv)² + ε²) with ``dv = v[src] − v[dst]``
+    gathered by the caller (see ref.edge_reweight_ref).
 
     m must be a multiple of EDGES_PER_BLOCK (the ops.py wrapper pads)."""
-    m = src.shape[0]
-    n = v.shape[0]
+    m = dv.shape[0]
     assert m % EDGES_PER_BLOCK == 0, m
-    grid = (m // EDGES_PER_BLOCK,)
-    eps_arr = jnp.asarray([eps], dtype=v.dtype)
+    edges = pl.BlockSpec((EDGES_PER_BLOCK,), lambda i: (i,))
     return pl.pallas_call(
         _edge_reweight_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((EDGES_PER_BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((EDGES_PER_BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((EDGES_PER_BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((EDGES_PER_BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m,), v.dtype),
+        grid=(m // EDGES_PER_BLOCK,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), edges, edges],
+        out_specs=edges,
+        out_shape=jax.ShapeDtypeStruct((m,), c.dtype),
         interpret=interpret,
-    )(src, dst, c, v, eps_arr)
+    )(_smem_scalar(eps, c.dtype), dv, c)
 
 
-def _fused_ell_sweep_kernel(cols_ref, ce_ref, cs_ref, ct_ref, v_ref, eps_ref,
+def _fused_ell_sweep_kernel(eps_ref, vn_ref, ce_ref, cs_ref, ct_ref, v_ref,
                             vals_ref, diag_ref, rs_ref, rt_ref):
-    i = pl.program_id(0)
-    cols = cols_ref[...]                  # (R, k) i32
-    ce = ce_ref[...]                      # (R, k) slot-major edge weights
-    v = v_ref[...]                        # (n,)
     eps = eps_ref[0]
-    rows = v_ref[pl.ds(i * ROWS_PER_BLOCK, ROWS_PER_BLOCK)]       # v[u]
-    z = ce * (rows[:, None] - jnp.take(v, cols, axis=0, fill_value=0))
+    ce = ce_ref[...]                      # (R, k) slot-major edge weights
+    rows = v_ref[...]                     # (R,) v[u]
+    z = ce * (rows[:, None] - vn_ref[...])
     r = (ce * ce) * jax.lax.rsqrt(z * z + eps * eps)
     vals_ref[...] = -r
     cs = cs_ref[...]
@@ -108,37 +101,23 @@ def _fused_ell_sweep_kernel(cols_ref, ce_ref, cs_ref, ct_ref, v_ref, eps_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_ell_sweep_pallas(cols: jax.Array, c_ell: jax.Array,
+def fused_ell_sweep_pallas(vn: jax.Array, c_ell: jax.Array,
                            c_s: jax.Array, c_t: jax.Array, v: jax.Array,
                            eps: jax.Array, *, interpret: bool = False):
     """(vals, diag, r_s, r_t) = one sweep over the slot-major edge data
-    (see ref.fused_ell_sweep_ref).  n must be a multiple of ROWS_PER_BLOCK
-    (the ops.py wrapper pads).
-
-    Halo-aware: ``v`` may be LONGER than the row count — the sharded solver
-    passes the halo-extended gather vector ``[v_local | exported boundary
-    values]`` (its first n entries are the row voltages, which is all the
-    row-slice read touches; ``cols`` may gather from the remote tail)."""
-    n, k = cols.shape
-    nv = v.shape[0]
+    (see ref.fused_ell_sweep_ref).  ``vn = v_full[cols]`` is gathered by the
+    caller and ``v`` holds the rows' own voltages; n must be a multiple of
+    ROWS_PER_BLOCK (the ops.py wrapper pads)."""
+    n, k = vn.shape
     assert n % ROWS_PER_BLOCK == 0, n
-    assert nv >= n, (nv, n)
-    grid = (n // ROWS_PER_BLOCK,)
-    eps_arr = jnp.asarray([eps], dtype=v.dtype)
-    row_spec = pl.BlockSpec((ROWS_PER_BLOCK,), lambda i: (i,))
-    tile_spec = pl.BlockSpec((ROWS_PER_BLOCK, k), lambda i: (i, 0))
+    row = pl.BlockSpec((ROWS_PER_BLOCK,), lambda i: (i,))
+    tile = pl.BlockSpec((ROWS_PER_BLOCK, k), lambda i: (i, 0))
     return pl.pallas_call(
         _fused_ell_sweep_kernel,
-        grid=grid,
-        in_specs=[
-            tile_spec,                                  # cols
-            tile_spec,                                  # c_ell
-            row_spec,                                   # c_s
-            row_spec,                                   # c_t
-            pl.BlockSpec((nv,), lambda i: (0,)),        # v (VMEM-resident)
-            pl.BlockSpec((1,), lambda i: (0,)),         # eps
-        ],
-        out_specs=[tile_spec, row_spec, row_spec, row_spec],
+        grid=(n // ROWS_PER_BLOCK,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),   # eps
+                  tile, tile, row, row, row],              # vn c_ell cs ct v
+        out_specs=[tile, row, row, row],
         out_shape=[
             jax.ShapeDtypeStruct((n, k), v.dtype),      # vals
             jax.ShapeDtypeStruct((n,), v.dtype),        # diag
@@ -146,4 +125,4 @@ def fused_ell_sweep_pallas(cols: jax.Array, c_ell: jax.Array,
             jax.ShapeDtypeStruct((n,), v.dtype),        # r_t
         ],
         interpret=interpret,
-    )(cols, c_ell, c_s, c_t, v, eps_arr)
+    )(_smem_scalar(eps, v.dtype), vn, c_ell, c_s, c_t, v)

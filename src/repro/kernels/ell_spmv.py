@@ -2,27 +2,27 @@
 
 TPU adaptation of the paper's per-process CSR matvec (DESIGN.md §2): CSR has
 ragged rows (branchy, serial on a vector unit), ELLPACK pads every row to a
-fixed lane count ``k`` so the gather + multiply-accumulate is perfectly
-regular: for road networks k≈8, for 26-connected MRI grids k≈32 — the pad
-waste is tiny and every lane maps onto the VPU's 8×128 lane grid.
+fixed lane count ``k`` so the multiply-accumulate is perfectly regular: for
+road networks k≈8, for 26-connected MRI grids k≈32.
+
+The neighbour gather ``v[cols]`` is NOT in the kernel: Mosaic lowers only
+2-D gathers within a vector register, not a row gather from an n-long
+vector.  The ``ops.ell_spmv`` wrapper gathers in XLA and hands the kernel
+the (n, k) neighbour values, so every operand streams through VMEM in row
+tiles and no block grows with n.
 
 Tiling scheme
 -------------
 grid = (n // ROWS_PER_BLOCK,)
-  cols  block : (R, k)  int32   VMEM   (R = ROWS_PER_BLOCK)
-  vals  block : (R, k)  f32     VMEM
-  diag  block : (R,)    f32     VMEM
-  v     block : (n,)    f32     VMEM   (full vector staged once per core; the
-                                        distributed layer shards rows so the
-                                        local v is the shard + halo, ≲2 MB)
-  out   block : (R,)    f32     VMEM
+  vn    block : (R, k)  f32   VMEM   neighbour voltages v[cols]
+  vals  block : (R, k)  f32   VMEM
+  diag  block : (R,)    f32   VMEM
+  v     block : (R,)    f32   VMEM   the rows' own voltages
+  out   block : (R,)    f32   VMEM
 
-Each step gathers v[cols_block] from the VMEM-resident vector (dynamic
-row-gather, int32 indices), multiplies by vals and reduces over lanes — an
-8×128-aligned elementwise+reduce per block, then adds diag ⊙ v_rows.
-
-VMEM budget per step (defaults R=512, k≤64, f32):
-  cols+vals ≤ 512·64·8 B = 256 KiB, v ≤ 4 MiB (1M-row shard), out 2 KiB.
+R = ROWS_PER_BLOCK = 1024: XLA tiles 1-D float32 arrays in 1024-element
+tiles on TPU, and Mosaic refuses a 1-D block that does not match them.
+VMEM per step (k ≤ 64, f32, double-buffered): ≤ 2·2·1024·64·4 B = 1 MiB.
 """
 from __future__ import annotations
 
@@ -32,40 +32,31 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-ROWS_PER_BLOCK = 512
+ROWS_PER_BLOCK = 1024
 
 
-def _ell_spmv_kernel(cols_ref, vals_ref, diag_ref, v_ref, out_ref):
-    i = pl.program_id(0)
-    cols = cols_ref[...]                  # (R, k) i32
-    vals = vals_ref[...]                  # (R, k)
-    v = v_ref[...]                        # (n,)
-    gathered = jnp.take(v, cols, axis=0, fill_value=0)  # (R, k) row gather
-    acc = jnp.sum(vals * gathered, axis=1)              # lane reduce
-    rows = v_ref[pl.ds(i * ROWS_PER_BLOCK, ROWS_PER_BLOCK)]
-    out_ref[...] = diag_ref[...] * rows + acc
+def _ell_spmv_kernel(vn_ref, vals_ref, diag_ref, v_ref, out_ref):
+    acc = jnp.sum(vals_ref[...] * vn_ref[...], axis=1)   # lane reduce
+    out_ref[...] = diag_ref[...] * v_ref[...] + acc
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ell_spmv_pallas(cols: jax.Array, vals: jax.Array, diag: jax.Array,
+def ell_spmv_pallas(vn: jax.Array, vals: jax.Array, diag: jax.Array,
                     v: jax.Array, *, interpret: bool = False) -> jax.Array:
-    """y = diag⊙v + Σ_lane vals⊙v[cols]  (see ref.ell_spmv_ref).
+    """y = diag⊙v + Σ_lane vals⊙vn, with ``vn = v_full[cols]`` gathered by
+    the caller (see ref.ell_spmv_ref).
 
     n must be a multiple of ROWS_PER_BLOCK (the ops.py wrapper pads).
     """
-    n, k = cols.shape
+    n, k = vn.shape
     assert n % ROWS_PER_BLOCK == 0, n
-    grid = (n // ROWS_PER_BLOCK,)
+    tile = pl.BlockSpec((ROWS_PER_BLOCK, k), lambda i: (i, 0))
+    row = pl.BlockSpec((ROWS_PER_BLOCK,), lambda i: (i,))
     return pl.pallas_call(
         _ell_spmv_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ROWS_PER_BLOCK, k), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_PER_BLOCK, k), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_PER_BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((n,), lambda i: (0,)),  # full v staged in VMEM
-        ],
-        out_specs=pl.BlockSpec((ROWS_PER_BLOCK,), lambda i: (i,)),
+        grid=(n // ROWS_PER_BLOCK,),
+        in_specs=[tile, tile, row, row],
+        out_specs=row,
         out_shape=jax.ShapeDtypeStruct((n,), v.dtype),
         interpret=interpret,
-    )(cols, vals, diag, v)
+    )(vn, vals, diag, v)

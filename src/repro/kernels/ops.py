@@ -1,13 +1,13 @@
 """Jit'd dispatch wrappers around the Pallas kernels.
 
-On TPU the kernels run compiled; on CPU (this container) they run in
-``interpret=True`` mode, which executes the kernel body in Python — the
-correctness contract the tests enforce against ref.py.  The wrappers own all
-padding so callers never see the block-size requirements.
+On TPU the kernels run compiled; on CPU they run in ``interpret=True`` mode,
+which executes the kernel body in Python — the correctness contract the
+tests enforce against ref.py.  Any other backend is an error: a kernel never
+falls back in silence.  The wrappers own all padding so callers never see
+the block-size requirements, and they do the voltage gathers in XLA (the
+kernels stream fixed tiles; see ell_spmv.py).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +20,11 @@ from .ell_spmv import ROWS_PER_BLOCK, ell_spmv_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"no Pallas path for backend {backend!r}: the "
+                           f"kernels compile for TPU and interpret on CPU")
+    return backend == "cpu"
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int = 0, value=0):
@@ -35,16 +39,13 @@ def _pad_to(x: jax.Array, mult: int, axis: int = 0, value=0):
 
 def ell_spmv(cols: jax.Array, vals: jax.Array, diag: jax.Array,
              v: jax.Array) -> jax.Array:
-    """ELLPACK SpMV (kernel on TPU / interpret elsewhere).  Pads the row
-    count to ROWS_PER_BLOCK; padded rows have diag=0, vals=0 → output 0."""
+    """ELLPACK SpMV (kernel on TPU / interpret on CPU).  Pads the row count
+    to ROWS_PER_BLOCK; padded rows have diag=0, vals=0 → output 0."""
     n = v.shape[0]
-    cols_p = _pad_to(cols, ROWS_PER_BLOCK)
-    vals_p = _pad_to(vals, ROWS_PER_BLOCK)
-    diag_p = _pad_to(diag, ROWS_PER_BLOCK)
-    n_pad = cols_p.shape[0]
-    # v is only padded for the diag⊙v row slice; gathers use fill_value=0
-    v_p = _pad_to(v, ROWS_PER_BLOCK) if n_pad != n else v
-    y = ell_spmv_pallas(cols_p, vals_p, diag_p, v_p, interpret=_interpret())
+    vn = _pad_to(v[cols], ROWS_PER_BLOCK)
+    y = ell_spmv_pallas(vn, _pad_to(vals, ROWS_PER_BLOCK),
+                        _pad_to(diag, ROWS_PER_BLOCK),
+                        _pad_to(v, ROWS_PER_BLOCK), interpret=_interpret())
     return y[:n]
 
 
@@ -52,10 +53,9 @@ def edge_reweight_r(src: jax.Array, dst: jax.Array, c: jax.Array,
                     v: jax.Array, eps) -> jax.Array:
     """Fused reweighted conductances r_e (padded edges get c=0 → r=0)."""
     m = src.shape[0]
-    src_p = _pad_to(src, EDGES_PER_BLOCK)
-    dst_p = _pad_to(dst, EDGES_PER_BLOCK)
-    c_p = _pad_to(c, EDGES_PER_BLOCK)
-    r = edge_reweight_pallas(src_p, dst_p, c_p, v, jnp.asarray(eps, v.dtype),
+    dv = _pad_to(v[src] - v[dst], EDGES_PER_BLOCK)
+    r = edge_reweight_pallas(dv, _pad_to(c, EDGES_PER_BLOCK),
+                             jnp.asarray(eps, v.dtype),
                              interpret=_interpret())
     return r[:m]
 
@@ -80,26 +80,19 @@ def edge_reweight(g, v: jax.Array, eps):
 
 def fused_ell_sweep(cols: jax.Array, c_ell: jax.Array, c_s: jax.Array,
                     c_t: jax.Array, v: jax.Array, eps):
-    """Single-sweep IRLS system build (kernel on TPU / interpret elsewhere):
+    """Single-sweep IRLS system build (kernel on TPU / interpret on CPU):
     (vals, diag, r_s, r_t) from one pass over the slot-major edge data.
     Pads the row count to ROWS_PER_BLOCK; padded rows carry c_ell = c_s =
     c_t = 0 → all outputs 0 there, sliced off before returning.
 
     ``v`` may be longer than the row count (the halo-extended gather vector
     of the sharded solver — its first ``cols.shape[0]`` entries are the row
-    voltages); padded rows then read the extended tail, harmlessly, since
-    their c_ell is 0."""
+    voltages, and ``cols`` may gather from the tail)."""
     n = cols.shape[0]
-    cols_p = _pad_to(cols, ROWS_PER_BLOCK)
-    ce_p = _pad_to(c_ell, ROWS_PER_BLOCK)
-    cs_p = _pad_to(c_s, ROWS_PER_BLOCK)
-    ct_p = _pad_to(c_t, ROWS_PER_BLOCK)
-    # the row-slice read needs len(v) ≥ padded row count; the R-multiple pad
-    # guarantees it because len(v) ≥ n already
-    v_p = _pad_to(v, ROWS_PER_BLOCK)
+    pad = lambda x: _pad_to(x, ROWS_PER_BLOCK)
     vals, diag, r_s, r_t = fused_ell_sweep_pallas(
-        cols_p, ce_p, cs_p, ct_p, v_p, jnp.asarray(eps, v.dtype),
-        interpret=_interpret())
+        pad(v[cols]), pad(c_ell), pad(c_s), pad(c_t), pad(v[:n]),
+        jnp.asarray(eps, v.dtype), interpret=_interpret())
     return vals[:n], diag[:n], r_s[:n], r_t[:n]
 
 

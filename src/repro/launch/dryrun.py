@@ -35,6 +35,7 @@ def run_one(arch: str, cell: str, multi_pod: bool, out_dir: str) -> dict:
     from repro.launch.cells import build_cell
     from repro.launch.mesh import make_production_mesh
     from repro.launch import hlo_analysis as ha
+    from repro.obs.perf.peaks import V5E
 
     mesh_name = "multi" if multi_pod else "single"
     t0 = time.time()
@@ -76,7 +77,7 @@ def run_one(arch: str, cell: str, multi_pod: bool, out_dir: str) -> dict:
     try:
         txt = compiled.as_text()
         costs = ha.analyze(txt, n_shards_default=n_chips)
-        terms = ha.roofline_terms(costs)
+        terms = ha.roofline_terms(costs, V5E)
         rec["hlo_costs"] = {
             "flops_per_chip": costs.flops,
             "hbm_bytes_per_chip": costs.hbm_bytes,

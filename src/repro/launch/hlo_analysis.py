@@ -400,20 +400,18 @@ def while_loop_collectives(text: str) -> List[Dict[str, object]]:
 
 
 # ---------------------------------------------------------------------------
-# Roofline terms (TPU v5e constants from the assignment)
+# Roofline terms (peaks from repro.obs.perf.peaks)
 # ---------------------------------------------------------------------------
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (~per-chip injection)
-
-
-def roofline_terms(costs: HloCosts) -> Dict[str, float]:
+def roofline_terms(costs: HloCosts, device_kind: str) -> Dict[str, float]:
     """Per-chip times in seconds (the HLO is already the per-device
-    program, so no further division by chip count)."""
-    t_compute = costs.flops / PEAK_FLOPS
-    t_memory = costs.hbm_bytes / HBM_BW
-    t_collective = costs.collective_bytes / ICI_BW
+    program, so no further division by chip count) against the published
+    peaks of ``device_kind``; KeyError for a chip the table lacks."""
+    from repro.obs.perf.peaks import PEAKS
+    pk = PEAKS[device_kind]
+    t_compute = costs.flops / pk.flops
+    t_memory = costs.hbm_bytes / pk.hbm_bytes_per_s
+    t_collective = costs.collective_bytes / pk.ici_bytes_per_s
     dominant = max((("compute", t_compute), ("memory", t_memory),
                     ("collective", t_collective)), key=lambda kv: kv[1])[0]
     return {"t_compute": t_compute, "t_memory": t_memory,

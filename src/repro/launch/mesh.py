@@ -9,13 +9,19 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the sharding rules and with_sharding_constraint calls name
+    # mesh axes, which jax.make_mesh's default Explicit axes refuse
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape=None, axes=("data", "model")):
@@ -27,4 +33,4 @@ def make_host_mesh(shape=None, axes=("data", "model")):
         while n % a:
             a -= 1
         shape = (a, n // a)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)

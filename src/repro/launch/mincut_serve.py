@@ -97,6 +97,8 @@ def main(argv=None) -> int:
                          "OUT.JSONL`)")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     if args.trace:
         from repro.obs import trace as _trace
         _trace.configure(enabled=True, jsonl=args.trace)

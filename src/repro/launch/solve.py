@@ -62,6 +62,8 @@ def main():
     args = ap.parse_args()
     backend = "sharded" if args.sharded else args.backend
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from repro.core import IRLSConfig, MinCutSession, Problem, max_flow
     from repro.core import rounding as rd
 

@@ -28,6 +28,8 @@ import os
 import re
 from typing import Any, Dict, Optional
 
+from .peaks import peaks_for
+
 __all__ = ["default_enabled", "compiled_costs", "program_costs",
            "per_solve_cost", "PROFILE_ENV"]
 
@@ -58,9 +60,7 @@ def compiled_costs(compiled) -> Dict[str, float]:
     for the trip-count correction.
     """
     text = compiled.as_text()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):               # jax<0.5 returns [dict]
-        ca = ca[0] if ca else {}
+    ca = compiled.cost_analysis() or {}
     raw_flops = float(ca.get("flops", 0.0) or 0.0)
     raw_bytes = float(ca.get("bytes accessed", 0.0) or 0.0)
 
@@ -80,32 +80,30 @@ def compiled_costs(compiled) -> Dict[str, float]:
 
 
 def program_costs(jitted, *example_args, **example_kwargs
-                  ) -> Optional[Dict[str, float]]:
+                  ) -> Dict[str, float]:
     """AOT lower + compile ``jitted`` at the example arguments (concrete
-    arrays or ``ShapeDtypeStruct``s) and extract its costs.  Returns
-    None instead of raising — profiling must never sink a solve."""
-    try:
-        compiled = jitted.lower(*example_args, **example_kwargs).compile()
-        return compiled_costs(compiled)
-    except Exception:
-        return None
+    arrays or ``ShapeDtypeStruct``s) and extract its costs.  A program
+    that does not compile raises: the solve it profiles would fail too."""
+    compiled = jitted.lower(*example_args, **example_kwargs).compile()
+    return compiled_costs(compiled)
 
 
 def per_solve_cost(cost: Optional[Dict[str, float]], seconds: float,
-                   calls: float = 1.0) -> Optional[Dict[str, Any]]:
+                   calls: float = 1.0, device_kind: Optional[str] = None
+                   ) -> Optional[Dict[str, Any]]:
     """Scale a per-execution cost record to one solve and derive rates.
 
     ``calls`` — program executions this solve ran (the host backend runs
     its compiled step once per IRLS iteration; scanned/sharded programs
     are whole-solve, calls=1).  ``seconds`` — the solve's IRLS wall.
-    Rates divide by wall seconds; the roofline fraction compares the
-    wall against the time the TPU-v5e roofline model says the program's
-    flops/bytes NEED (``hlo_analysis.roofline_terms`` constants) — on a
-    CPU host it is tiny, on the target mesh it approaches 1.
+    ``device_kind`` — the chip the solve ran on (default: the first JAX
+    device).  Rates and the roofline fraction (the time the chip's
+    published peaks say the flops/bytes NEED, over the wall) are written
+    only for a device in ``peaks.PEAKS``; on any other device the record
+    carries the counts alone.
     """
     if cost is None:
         return None
-    from repro.launch.hlo_analysis import HBM_BW, ICI_BW, PEAK_FLOPS
     flops = cost["flops"] * calls
     hbm = cost["hbm_bytes"] * calls
     coll = cost.get("collective_bytes", 0.0) * calls
@@ -114,9 +112,14 @@ def per_solve_cost(cost: Optional[Dict[str, float]], seconds: float,
         "program_calls": float(calls),
         "while_trip_scale": cost.get("while_trip_scale", 1.0),
     }
-    if seconds and seconds > 0:
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    pk = peaks_for(device_kind)
+    if pk is not None and seconds and seconds > 0:
         out["achieved_gflops"] = flops / seconds / 1e9
         out["achieved_gbps"] = hbm / seconds / 1e9
-        t_roof = max(flops / PEAK_FLOPS, hbm / HBM_BW, coll / ICI_BW)
+        t_roof = max(flops / pk.flops, hbm / pk.hbm_bytes_per_s,
+                     coll / pk.ici_bytes_per_s)
         out["roofline_fraction"] = t_roof / seconds
     return out

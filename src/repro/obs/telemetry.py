@@ -26,7 +26,8 @@ timings alone cannot explain:
                        profile: cost_analysis × while-trip correction);
                        None when profiling is off
     achieved_gflops    flops / irls wall seconds / 1e9 (+ achieved_gbps,
-                       roofline_fraction vs the TPU-v5e roofline model)
+                       roofline_fraction against the chip's published
+                       peaks); None on a device the peaks table lacks
     clamped_reweights  sharded reweight-clamp hits this solve (the
                        cfg.reweight_clamp float32 mitigation); None when
                        not applicable

@@ -136,12 +136,11 @@ def build_pipeline_loss(cfg: tr.LMConfig, mesh: Mesh, rules: ShardingRules,
         return jax.lax.psum(loss_acc, pod_axis) / M
 
     layer_keys = stage_param_shapes(cfg, n_stages)["layers"].keys()
-    from repro.distributed.collectives import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=({"embed": P(), "final_norm": P(),
                    "layers": {k: P(pod_axis) for k in layer_keys}},
                   P()),
         out_specs=P(),
-        axis_names={pod_axis})
+        axis_names={pod_axis}, check_vma=False)
     return fn

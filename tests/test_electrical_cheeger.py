@@ -2,7 +2,7 @@
 import numpy as np
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import cheeger_lambda2, max_flow, phi_of_cut
 from repro.core.incidence import device_graph_from_instance

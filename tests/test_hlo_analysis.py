@@ -202,13 +202,32 @@ class TestCostAnalysisCorrection:
         from repro.obs.perf import profile as perf_profile
         cost = {"flops": 1e9, "hbm_bytes": 4e9, "collective_bytes": 0.0,
                 "cost_analysis_flops": 5e8, "while_trip_scale": 2.0}
-        per = perf_profile.per_solve_cost(cost, seconds=0.5, calls=3.0)
+        from repro.obs.perf.peaks import PEAKS, V5E
+        per = perf_profile.per_solve_cost(cost, seconds=0.5, calls=3.0,
+                                          device_kind=V5E)
         assert per["flops"] == pytest.approx(3e9)
         assert per["achieved_gflops"] == pytest.approx(3e9 / 0.5 / 1e9)
         assert per["achieved_gbps"] == pytest.approx(3 * 4e9 / 0.5 / 1e9)
         # roofline fraction: best-case time over measured time
-        best = max(3e9 / ha.PEAK_FLOPS, 3 * 4e9 / ha.HBM_BW)
+        pk = PEAKS[V5E]
+        best = max(3e9 / pk.flops, 3 * 4e9 / pk.hbm_bytes_per_s)
         assert per["roofline_fraction"] == pytest.approx(best / 0.5)
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v4", None])
+    def test_per_solve_cost_unknown_device_has_no_rates(self, kind):
+        """A device missing from the peaks table gets counts, never rates
+        or a roofline share borrowed from another chip."""
+        from repro.obs.perf import profile as perf_profile
+        cost = {"flops": 1e9, "hbm_bytes": 4e9, "collective_bytes": 0.0,
+                "cost_analysis_flops": 1e9, "while_trip_scale": 1.0}
+        if kind is None:     # default: the process's own device (the CPU)
+            per = perf_profile.per_solve_cost(cost, seconds=0.5)
+        else:
+            per = perf_profile.per_solve_cost(cost, seconds=0.5,
+                                              device_kind=kind)
+        assert per["flops"] == pytest.approx(1e9)
+        for key in ("achieved_gflops", "achieved_gbps", "roofline_fraction"):
+            assert key not in per
 
     def test_per_solve_cost_handles_missing(self):
         from repro.obs.perf import profile as perf_profile

@@ -3,7 +3,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import IRLSConfig, solve
 from repro.core.incidence import (device_graph_from_instance, l1_objective,

@@ -3,7 +3,7 @@ import itertools
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.maxflow import max_flow
 from repro.graphs import generators as gen

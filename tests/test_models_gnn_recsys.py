@@ -4,7 +4,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import registry
 from repro.configs.gnn import REDUCED_CELL

@@ -1,7 +1,7 @@
 """Partitioner + graph substrate tests."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.graphs import generators as gen
 from repro.graphs import partition as gp
@@ -17,6 +17,19 @@ def test_partition_balanced_and_valid(p):
     part_w = np.zeros(p)
     np.add.at(part_w, labels, w)
     assert part_w.max() <= part_w.sum() / p * 1.6  # balanced-ish
+
+
+@pytest.mark.parametrize("family,p", [("road", 64), ("grid3d_26", 32)])
+def test_partition_node_counts_balanced_at_many_parts(family, p):
+    """Many parts on a sparse road network and a 26-connected grid: the
+    largest part (the dense block-Jacobi block size) stays within 2x the
+    mean node count, so no part swallows the graph."""
+    g = (gen.road_like(100, seed=0) if family == "road"
+         else gen.grid_3d(16, 16, 16, conn=26, seed=0))
+    labels = gp.partition_kway(g, p, seed=0)
+    assert labels.min() >= 0 and labels.max() < p
+    counts = np.bincount(labels, minlength=p)
+    assert counts.max() <= 2 * g.n / p, (counts.max(), g.n / p)
 
 
 def test_partition_cut_beats_random():

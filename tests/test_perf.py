@@ -293,20 +293,26 @@ class TestProfiling:
         return gen.segmentation_instance(g, (8, 8), seed=4)
 
     def test_host_and_scanned_telemetry_flops(self, small_instance):
+        import jax
         from repro.core import IRLSConfig, MinCutSession
+        from repro.obs.perf.peaks import peaks_for
         cfg = IRLSConfig(n_irls=4, pcg_max_iters=30)
         sess = MinCutSession(small_instance, cfg, profile=True)
+        # the CPU has no entry in the peaks table: the counts are written,
+        # the device rates and roofline share are not
+        assert peaks_for(jax.devices()[0].device_kind) is None
         for backend in ("host", "scanned"):
             t = sess.solve(backend=backend).telemetry
             assert t["flops"] and t["flops"] > 0, backend
-            assert t["achieved_gflops"] and t["achieved_gflops"] > 0, backend
-            assert t["roofline_fraction"] > 0, backend
+            assert t["hbm_bytes"] and t["hbm_bytes"] > 0, backend
+            assert t["achieved_gflops"] is None, backend
+            assert t["roofline_fraction"] is None, backend
         costs = sess.program_costs()
         assert {"host", "scanned/False"} <= set(costs)
         snap = sess.telemetry.snapshot()
         assert snap["total_flops"] > 0
         assert snap["profiled_solves"] == 2
-        assert snap["mean_achieved_gflops"] > 0
+        assert math.isnan(snap["mean_achieved_gflops"])
 
     def test_profile_off_leaves_telemetry_none(self, small_instance):
         from repro.core import IRLSConfig, MinCutSession

@@ -1,7 +1,7 @@
 """Sweep cut + two-level rounding (paper §3.4, Prop 3.1)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import max_flow, sweep_cut, two_level
 from repro.core.rounding import coarsen, kmeans_thresholds
