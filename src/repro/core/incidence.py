@@ -28,6 +28,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import named_scope
+
 
 class DeviceGraph(NamedTuple):
     """Device-resident s-t instance (see graphs.structures.STInstance).
@@ -77,6 +79,7 @@ def edge_residuals(g: DeviceGraph, v: jax.Array):
     return z_edges, z_s, z_t
 
 
+@named_scope("irls.objective")
 def smoothed_objective(g: DeviceGraph, v: jax.Array, eps: float) -> jax.Array:
     """S_ε(x) = Σ_e sqrt((CBx)_e² + ε²)  (eq. 9), full-graph edge sum.
 
@@ -91,6 +94,7 @@ def smoothed_objective(g: DeviceGraph, v: jax.Array, eps: float) -> jax.Array:
     return s
 
 
+@named_scope("irls.objective")
 def l1_objective(g: DeviceGraph, v: jax.Array) -> jax.Array:
     """Exact ‖C B x‖₁ (the fractional cut value of the embedding x)."""
     z_e, z_s, z_t = edge_residuals(g, v)

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace
 from . import adaptive as sched
 from . import laplacian as lap
 from . import precond as pc
@@ -179,6 +180,15 @@ def _reweight(g: DeviceGraph, v, eps, cfg: IRLSConfig) -> lap.Reweighted:
     return lap.reweight(g, v, eps)
 
 
+@trace.named_scope("irls.system")
+def _initial_system(g: DeviceGraph, cfg: IRLSConfig,
+                    ell_plan: Optional[lap.EllPlan]):
+    """The first WLS system, with W⁰ = C: returns ``(matvec, b, rw)``."""
+    rw = lap.initial_weights(g)
+    return _make_matvec(g, rw, cfg, ell_plan), lap.rhs(rw), rw
+
+
+@trace.named_scope("irls.system")
 def _iteration_system(g: DeviceGraph, cfg: IRLSConfig,
                       ell_plan: Optional[lap.EllPlan], c_ell, v, eps):
     """Build one IRLS iteration's system: returns ``(matvec, b, rw)``.
@@ -252,9 +262,7 @@ class _Stepper:
         cfg = self.cfg
         g = DeviceGraph(src=self.g.src, dst=self.g.dst, c=c, c_s=c_s, c_t=c_t)
         if first:
-            rw = lap.initial_weights(g)
-            matvec = _make_matvec(g, rw, cfg, self.ell_plan)
-            b = lap.rhs(rw)
+            matvec, b, rw = _initial_system(g, cfg, self.ell_plan)
         else:
             matvec, b, rw = _iteration_system(g, cfg, self.ell_plan, c_ell,
                                               v, eps)
@@ -288,6 +296,11 @@ def run_host_loop(stepper: _Stepper, cfg: IRLSConfig, n: int, dtype,
     fractional cut value's relative change stays below it; ``adaptive_tol``
     feeds a per-iteration inner tolerance (traced argument — no
     recompilation) to the stepper's PCG.
+
+    Each iteration opens two spans (no-ops with tracing off):
+    ``session.irls.dispatch`` around the step's launch (attribute ``l``, 0
+    for the cold initial solve) and ``session.irls.readback`` around the
+    device→host reads of its diagnostics and the adaptive state machine.
     """
     diag = IRLSDiagnostics(pcg_iters=[], pcg_residuals=[], objective=[],
                            l1_objective=[],
@@ -303,30 +316,34 @@ def run_host_loop(stepper: _Stepper, cfg: IRLSConfig, n: int, dtype,
     if v0 is None:
         v = jnp.zeros((n,), dtype=dtype)
         # x⁰: WLS with W⁰ = C (cold start by definition)
-        v, iters, rel, s_eps, frac = stepper._step(v, cfg.eps, first=True,
-                                                   weights=weights, tol=tol_l)
-        _record(diag, v, iters, rel, s_eps, frac, collect_voltages)
-        if adaptive:
-            st = sched.init_state(cfg, float(frac), tight)
+        with trace.span("session.irls.dispatch", l=0):
+            v, iters, rel, s_eps, frac = stepper._step(
+                v, cfg.eps, first=True, weights=weights, tol=tol_l)
+        with trace.span("session.irls.readback"):
+            _record(diag, v, iters, rel, s_eps, frac, collect_voltages)
+            if adaptive:
+                st = sched.init_state(cfg, float(frac), tight)
     else:
         v = jnp.asarray(v0, dtype=dtype)
     for l in range(1, cfg.n_irls + 1):
         eps_l = _eps_at(cfg, l)
-        v, iters, rel, s_eps, frac = stepper._step(v, eps_l, first=False,
-                                                   weights=weights, tol=tol_l,
-                                                   c_ell=c_ell)
-        _record(diag, v, iters, rel, s_eps, frac, collect_voltages)
-        if not adaptive:
-            continue
-        if st is None:           # warm start: first reading seeds the state
-            st = sched.init_state(cfg, float(frac), tight)
-            continue
-        st = sched.advance(cfg, st, float(frac), float(rel), int(iters),
-                           tight)
-        if cfg.adaptive_tol:
-            tol_l = float(st.tol)
-        if bool(st.done):
-            break                  # converged: stop paying for matvecs
+        with trace.span("session.irls.dispatch", l=l):
+            v, iters, rel, s_eps, frac = stepper._step(
+                v, eps_l, first=False, weights=weights, tol=tol_l,
+                c_ell=c_ell)
+        with trace.span("session.irls.readback"):
+            _record(diag, v, iters, rel, s_eps, frac, collect_voltages)
+            if not adaptive:
+                continue
+            if st is None:       # warm start: first reading seeds the state
+                st = sched.init_state(cfg, float(frac), tight)
+                continue
+            st = sched.advance(cfg, st, float(frac), float(rel), int(iters),
+                               tight)
+            if cfg.adaptive_tol:
+                tol_l = float(st.tol)
+            if bool(st.done):
+                break              # converged: stop paying for matvecs
     v.block_until_ready()
     diag.irls_time = time.perf_counter() - t1
     return v, diag
@@ -450,10 +467,8 @@ def make_scanned_program(src, dst, cfg: IRLSConfig,
         if warm:
             v0 = v_warm.astype(c.dtype)
         else:
-            rw0 = lap.initial_weights(g)
-            matvec0 = _make_matvec(g, rw0, cfg, ell_plan)
+            matvec0, b0, rw0 = _initial_system(g, cfg, ell_plan)
             apply_M0 = _scanned_precond(cfg, rw0, matvec0, block_plan)
-            b0 = lap.rhs(rw0)
             if adaptive:
                 tol0 = sched.initial_tol(cfg, cfg.pcg_tight_tol)
                 res0 = pcg_masked(matvec0, b0, precond=apply_M0, tol=tol0,

@@ -48,6 +48,8 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import named_scope
+
 
 class PCGResult(NamedTuple):
     x: jax.Array          # solution
@@ -74,6 +76,7 @@ def _resolve_dots(dot, dot2):
     return dot, dot2
 
 
+@named_scope("irls.pcg")
 def pcg(matvec: Callable[[jax.Array], jax.Array],
         b: jax.Array,
         x0: Optional[jax.Array] = None,
@@ -132,6 +135,7 @@ def pcg(matvec: Callable[[jax.Array], jax.Array],
                      history=history)
 
 
+@named_scope("irls.pcg")
 def pcg_masked(matvec, b, x0=None, precond=None, tol=1e-3,
                max_iters: int = 50, dot=None, dot2=None) -> PCGResult:
     """Fixed-shape masked-update PCG with early exit (no history buffer).
@@ -190,6 +194,7 @@ def pcg_masked(matvec, b, x0=None, precond=None, tol=1e-3,
                      history=jnp.zeros((1,), dtype=b.dtype))
 
 
+@named_scope("irls.pcg")
 def pcg_fixed_iters(matvec, b, x0=None, precond=None, n_iters: int = 50,
                     record_history: bool = True, dot=None, dot2=None):
     """PCG with a fixed iteration count via ``lax.scan`` — fully static

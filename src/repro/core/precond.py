@@ -30,6 +30,7 @@ from typing import Callable, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import named_scope
 from .incidence import DeviceGraph
 from .laplacian import Reweighted
 
@@ -47,12 +48,15 @@ def register(name: str):
     return deco
 
 
+@named_scope("irls.factor")
 def make_preconditioner(name: str, rw: Reweighted, matvec, cfg,
                         block_plan: Optional["BlockPlan"] = None):
     """Resolve ``name`` through REGISTRY and build the per-iteration apply.
 
     Returns a callable ``x → M⁻¹x`` or None (identity).  Raises ValueError
-    on unknown names, listing what is registered."""
+    on unknown names, listing what is registered.  Construction (block
+    assembly, Cholesky, explicit inverse) runs under the ``irls.factor``
+    scope; the apply runs where PCG calls it, under ``irls.pcg``."""
     try:
         factory = REGISTRY[name]
     except KeyError:

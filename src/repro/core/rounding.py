@@ -32,6 +32,7 @@ except Exception:  # pragma: no cover
     jax = None
 
 from repro.graphs.structures import EdgeList, STInstance
+from repro.obs import trace
 
 
 class RoundingResult(NamedTuple):
@@ -210,27 +211,37 @@ def coarsen(instance: STInstance, v: np.ndarray, gamma0: float,
 def two_level(instance: STInstance, v: np.ndarray,
               margin: float = 0.05) -> RoundingResult:
     """The paper's two-level rounding: coarsen by polarization, solve the
-    coarse instance EXACTLY, lift."""
-    gamma0, gamma1 = kmeans_thresholds(np.asarray(v), margin=margin)
-    coarse, labels, contour_ids, st_cross = coarsen(instance, v, gamma0, gamma1)
+    coarse instance EXACTLY, lift.  Each step is a span (no-op with tracing
+    off): ``session.rounding.thresholds``, ``.coarsen``, ``.maxflow``
+    (attributes ``coarse_n``, ``coarse_m``) and ``.lift``."""
+    with trace.span("session.rounding.thresholds"):
+        gamma0, gamma1 = kmeans_thresholds(np.asarray(v), margin=margin)
+    with trace.span("session.rounding.coarsen"):
+        coarse, labels, contour_ids, st_cross = coarsen(instance, v, gamma0,
+                                                        gamma1)
     from .maxflow import max_flow
     if coarse.n == 0:
         # degenerate coarsening (fully polarized voltages): the threshold
         # assignment IS the cut; fall back to the better of it and sweep
-        in_source = labels == 1
-        thr = RoundingResult(in_source=in_source,
-                             cut_value=instance.cut_value(in_source),
-                             meta={"method": "two_level", "gamma0": gamma0,
-                                   "gamma1": gamma1, "coarse_n": 0,
-                                   "reduction": float(instance.n + 2)})
-        sw = sweep_cut(instance, v)
+        with trace.span("session.rounding.lift"):
+            in_source = labels == 1
+            thr = RoundingResult(in_source=in_source,
+                                 cut_value=instance.cut_value(in_source),
+                                 meta={"method": "two_level",
+                                       "gamma0": gamma0, "gamma1": gamma1,
+                                       "coarse_n": 0,
+                                       "reduction": float(instance.n + 2)})
+            sw = sweep_cut(instance, v)
         return thr if thr.cut_value <= sw.cut_value else \
             RoundingResult(in_source=sw.in_source, cut_value=sw.cut_value,
                            meta=dict(thr.meta, fallback="sweep"))
-    res = max_flow(coarse)
-    in_source = labels == 1
-    in_source[contour_ids] = res.in_source[: coarse.n]
-    exact = instance.cut_value(in_source)
+    with trace.span("session.rounding.maxflow", coarse_n=int(coarse.n),
+                    coarse_m=int(coarse.graph.m)):
+        res = max_flow(coarse)
+    with trace.span("session.rounding.lift"):
+        in_source = labels == 1
+        in_source[contour_ids] = res.in_source[: coarse.n]
+        exact = instance.cut_value(in_source)
     meta = {
         "method": "two_level", "gamma0": gamma0, "gamma1": gamma1,
         "coarse_n": int(coarse.n), "coarse_m": int(coarse.graph.m),

@@ -228,20 +228,26 @@ class Problem:
         """Partition (unless ``labels`` given) and reorder the instance.
 
         ``n_blocks <= 1`` skips partitioning and reordering entirely (the
-        point-Jacobi / Chebyshev regimes need neither).
+        point-Jacobi / Chebyshev regimes need neither).  The two steps are
+        the spans ``session.problem.partition`` (attribute ``n_blocks``)
+        and ``session.problem.reorder``.
         """
         from repro.graphs import partition as gp
 
         n = instance.n
         if n_blocks > 1:
             if labels is None:
-                labels = gp.partition_kway(instance.graph, n_blocks, seed=seed)
-            labels = np.asarray(labels, dtype=np.int64)
-            perm = gp.partition_order(labels)
-            inv = np.empty_like(perm)
-            inv[perm] = np.arange(n)
-            inst_r = permute_instance(instance, perm)
-            labels_sorted = np.sort(labels)
+                with trace.span("session.problem.partition",
+                                n_blocks=int(n_blocks)):
+                    labels = gp.partition_kway(instance.graph, n_blocks,
+                                               seed=seed)
+            with trace.span("session.problem.reorder"):
+                labels = np.asarray(labels, dtype=np.int64)
+                perm = gp.partition_order(labels)
+                inv = np.empty_like(perm)
+                inv[perm] = np.arange(n)
+                inst_r = permute_instance(instance, perm)
+                labels_sorted = np.sort(labels)
         else:
             labels = np.zeros(n, dtype=np.int64)
             labels_sorted = labels
@@ -468,8 +474,9 @@ class MinCutSession:
         # continuous profiling (repro.obs.perf.profile): per-compile-key
         # FLOP/byte estimates of the cached compiled programs, attached to
         # SolveResult.telemetry as achieved GFLOP/s.  Costs one extra AOT
-        # compile per program key, so None = auto (on when tracing or
-        # REPRO_PROFILE says so — bench/CLI runs — off in plain tests).
+        # compile per program key, so None = auto (on when REPRO_PROFILE
+        # says so — benchmarks/run.py and bench_diff — off otherwise, also
+        # with tracing on).
         self._profile = profile
         self._program_costs: Dict[tuple, Optional[dict]] = {}
         self._scanned_raw: Dict[tuple, object] = {}

@@ -18,10 +18,19 @@ each appears in a traced serve replay; docs/API.md "Observability" has
 the full schema):
 
     serve.*     engine queue/assembly/dispatch/flush  (serve/engine.py)
-    session.*   solve / solve_batch / presolve / irls / rounding phases
+    session.*   solve / solve_batch / presolve / irls / rounding phases;
+                irls.dispatch / irls.readback per host IRLS iteration
+                (core/irls.py); rounding.thresholds / .coarsen / .maxflow /
+                .lift (core/rounding.py); problem.partition / .reorder
+                (Problem.build)
     presolve.*  kernelization fixpoint                (presolve/contract.py)
     cuttree.*   build / wave / speculation            (cuttree/gusfield.py)
     sharded.*   SPMD solve + collective gauges        (distributed/solver.py)
+
+Device phases (``jax.named_scope`` via ``trace.named_scope``; HLO
+metadata, so a profiler trace's ``tf_op`` paths carry them):
+``irls.system`` (system build), ``irls.factor`` (preconditioner
+construction), ``irls.pcg`` (the PCG solve), ``irls.objective``.
 """
 from . import dashboard, metrics, telemetry, trace
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, Reservoir,

@@ -17,10 +17,12 @@ LOWER BOUND under adaptive schedules, which is the honest direction for
 an achieved-GFLOP/s figure.
 
 Profiling pays one extra AOT ``lower().compile()`` per compiled-program
-cache key (≈0.2–1 s), so it is OFF for plain solves and ON when the
-obs tracing layer is enabled or ``REPRO_PROFILE=1`` — the bench harness
-and the ``bench_diff`` CLI set the env var, so every recorded bench
-payload carries achieved GFLOP/s without taxing the unit-test hot path.
+cache key (≈0.2–1 s), so it is OFF unless ``REPRO_PROFILE=1`` (or a
+session's ``profile=True``) — ``benchmarks/run.py`` and the ``bench_diff``
+CLI set the env var, so every recorded bench payload carries achieved
+GFLOP/s without taxing the unit-test hot path.  Turning span tracing on
+does not turn profiling on: a traced run compiles the same programs as
+an untraced one.
 """
 from __future__ import annotations
 
@@ -39,16 +41,10 @@ _TRIP_MASK = re.compile(r"known_trip_count")
 
 
 def default_enabled() -> bool:
-    """Profile by default?  ``REPRO_PROFILE`` (1/0) wins; otherwise
-    follow the tracing switch — a traced run wants the device-side
-    counters, an untraced unit test wants the compile time back."""
+    """Profile by default?  Only when ``REPRO_PROFILE`` says so (1/true/
+    on/yes); the tracing switch plays no part."""
     env = os.environ.get(PROFILE_ENV, "").strip().lower()
-    if env in ("1", "true", "on", "yes"):
-        return True
-    if env in ("0", "false", "off", "no"):
-        return False
-    from repro.obs import trace
-    return trace.enabled()
+    return env in ("1", "true", "on", "yes")
 
 
 def compiled_costs(compiled) -> Dict[str, float]:

@@ -32,9 +32,15 @@ Design constraints (this is hot-path adjacent code):
 * **Profiler passthrough.**  ``configure(profiler=True)`` additionally
   wraps each span in ``jax.profiler.TraceAnnotation`` so the same names
   show up on the device timeline in a real profiler trace.
+* **Device phases are named at trace time.**  ``@named_scope("irls.pcg")``
+  runs a function under ``jax.named_scope``: every operation it traces
+  carries the name in its HLO ``op_name`` metadata, which a profiler trace
+  keeps as the op's ``tf_op`` path.  Compile-time only — no runtime cost
+  and no switch.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -43,7 +49,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional
 
 __all__ = ["Tracer", "SpanRecord", "get_tracer", "configure", "enabled",
-           "span", "event", "spans", "clear", "fence"]
+           "span", "event", "spans", "clear", "fence", "named_scope"]
 
 
 class SpanRecord:
@@ -308,3 +314,19 @@ def fence(*vals):
         for v in vals:
             jax.block_until_ready(v)
     return vals[0] if len(vals) == 1 else vals
+
+
+def named_scope(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``.
+
+    A fresh scope is entered per call: one ``jax.named_scope`` object used
+    as a decorator keeps its saved state on itself, which nested calls and
+    concurrent tracing threads would overwrite."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            import jax
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco

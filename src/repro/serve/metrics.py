@@ -125,7 +125,7 @@ class ServeMetrics:
     def record_solve_cost(self, flops: Optional[float],
                           achieved_gflops: Optional[float]) -> None:
         """Continuous-profiling figures of one served solve (present when
-        the session profiles — ``REPRO_PROFILE=1`` or tracing on): total
+        the session profiles — ``REPRO_PROFILE=1`` or ``profile=True``): total
         device flops as an exact counter, achieved GFLOP/s as a bounded
         sample series — both land in ``prometheus_text``."""
         if flops:
