@@ -11,9 +11,11 @@ vector x^(T) then goes to a rounding procedure (core/rounding.py).
 Two drivers are provided:
 
 * ``solve`` — host-driven loop: each IRLS iteration is one jitted step, the
-  preconditioner is refactorized between iterations, residual/objective
-  diagnostics are collected.  This is the reference/production single-host
-  path, and is what the paper measures per-phase (Table 2).
+  preconditioner is refactorized between iterations (only in those whose
+  warm start misses the PCG tolerance — ``pcg``'s lazy builder), and
+  residual/objective diagnostics are collected.  This is the
+  reference/production single-host path, and is what the paper measures
+  per-phase (Table 2).
 * ``solve_scanned`` — one jitted ``lax.scan`` over IRLS iterations — the form
   the distributed dry-run lowers and compiles, and the batched serving hot
   path (``jax.vmap`` over same-topology weight vectors).
@@ -128,6 +130,14 @@ class IRLSDiagnostics:
     voltages: Optional[List[np.ndarray]]  # per-iteration x (polarization study)
     setup_time: float = 0.0
     irls_time: float = 0.0
+    # per iteration: was the preconditioner built?  False where the warm
+    # start already met the PCG tolerance (0 PCG steps, factorization skipped)
+    precond_built: List[bool] = dataclasses.field(default_factory=list)
+
+    @property
+    def precond_builds(self) -> int:
+        """Preconditioner constructions in this solve."""
+        return sum(self.precond_built)
 
 
 def _eps_at(cfg: IRLSConfig, l: int) -> float:
@@ -266,14 +276,15 @@ class _Stepper:
         else:
             matvec, b, rw = _iteration_system(g, cfg, self.ell_plan, c_ell,
                                               v, eps)
-        apply_M = pc.make_preconditioner(cfg.precond, rw, matvec, cfg,
-                                         self.block_plan)
         x0 = v if (cfg.warm_start and not first) else jnp.zeros_like(v)
-        res = pcg(matvec, b, x0=x0, precond=apply_M, tol=tol,
-                  max_iters=cfg.pcg_max_iters, record_history=True)
+        # built only when x0 misses the tolerance (see ``pcg``)
+        res = pcg(matvec, b, x0=x0, tol=tol, max_iters=cfg.pcg_max_iters,
+                  record_history=True,
+                  make_precond=lambda: pc.make_preconditioner(
+                      cfg.precond, rw, matvec, cfg, self.block_plan))
         s_eps = smoothed_objective(g, res.x, eps)
         frac_cut = l1_objective(g, res.x)
-        return res.x, res.iters, res.rel_res, s_eps, frac_cut
+        return res.x, res.iters, res.rel_res, s_eps, frac_cut, res.factored
 
 
 def run_host_loop(stepper: _Stepper, cfg: IRLSConfig, n: int, dtype,
@@ -300,7 +311,9 @@ def run_host_loop(stepper: _Stepper, cfg: IRLSConfig, n: int, dtype,
     Each iteration opens two spans (no-ops with tracing off):
     ``session.irls.dispatch`` around the step's launch (attribute ``l``, 0
     for the cold initial solve) and ``session.irls.readback`` around the
-    device→host reads of its diagnostics and the adaptive state machine.
+    device→host reads of its diagnostics and the adaptive state machine,
+    with attribute ``factored`` (1 when the step built the preconditioner,
+    0 when its warm start met the PCG tolerance and the build was skipped).
     """
     diag = IRLSDiagnostics(pcg_iters=[], pcg_residuals=[], objective=[],
                            l1_objective=[],
@@ -317,10 +330,11 @@ def run_host_loop(stepper: _Stepper, cfg: IRLSConfig, n: int, dtype,
         v = jnp.zeros((n,), dtype=dtype)
         # x⁰: WLS with W⁰ = C (cold start by definition)
         with trace.span("session.irls.dispatch", l=0):
-            v, iters, rel, s_eps, frac = stepper._step(
+            v, iters, rel, s_eps, frac, built = stepper._step(
                 v, cfg.eps, first=True, weights=weights, tol=tol_l)
-        with trace.span("session.irls.readback"):
-            _record(diag, v, iters, rel, s_eps, frac, collect_voltages)
+        with trace.span("session.irls.readback") as sp:
+            _record(diag, sp, v, iters, rel, s_eps, frac, built,
+                    collect_voltages)
             if adaptive:
                 st = sched.init_state(cfg, float(frac), tight)
     else:
@@ -328,11 +342,12 @@ def run_host_loop(stepper: _Stepper, cfg: IRLSConfig, n: int, dtype,
     for l in range(1, cfg.n_irls + 1):
         eps_l = _eps_at(cfg, l)
         with trace.span("session.irls.dispatch", l=l):
-            v, iters, rel, s_eps, frac = stepper._step(
+            v, iters, rel, s_eps, frac, built = stepper._step(
                 v, eps_l, first=False, weights=weights, tol=tol_l,
                 c_ell=c_ell)
-        with trace.span("session.irls.readback"):
-            _record(diag, v, iters, rel, s_eps, frac, collect_voltages)
+        with trace.span("session.irls.readback") as sp:
+            _record(diag, sp, v, iters, rel, s_eps, frac, built,
+                    collect_voltages)
             if not adaptive:
                 continue
             if st is None:       # warm start: first reading seeds the state
@@ -378,11 +393,14 @@ def solve(instance, cfg: IRLSConfig = IRLSConfig(),
     return prob.to_original(np.asarray(v)), diag
 
 
-def _record(diag, v, iters, rel, s_eps, frac, collect_voltages):
+def _record(diag, span, v, iters, rel, s_eps, frac, built,
+            collect_voltages):
     diag.pcg_iters.append(int(iters))
     diag.pcg_residuals.append(float(rel))
     diag.objective.append(float(s_eps))
     diag.l1_objective.append(float(frac))
+    diag.precond_built.append(bool(built))
+    span.set(factored=int(diag.precond_built[-1]))
     if collect_voltages and diag.voltages is not None:
         diag.voltages.append(np.asarray(v).copy())
 

@@ -15,7 +15,10 @@ sqrt only happens when a history entry is recorded or at the very end.
 
 Three variants share the same update rule:
 
-* ``pcg``             — tolerance + cap ``while_loop`` (host driver).
+* ``pcg``             — tolerance + cap ``while_loop`` (host driver),
+  gated on the initial residual: a warm start that already meets the
+  tolerance skips the preconditioner's construction as well as the loop.
+  Not for ``jax.vmap`` (the gate would run both branches).
 * ``pcg_masked``      — fixed-shape early exit with EXPLICITLY masked
   updates: once a lane converges its state stops changing, so under
   ``jax.vmap`` a batch stops paying for finished instances (the batch
@@ -56,6 +59,8 @@ class PCGResult(NamedTuple):
     iters: jax.Array      # iterations taken (i32 scalar)
     rel_res: jax.Array    # final relative residual
     history: jax.Array    # f[max_iters+1] residual norms (NaN-padded)
+    factored: Optional[jax.Array] = None  # pcg only: the preconditioner
+                                          # was built (PCG took a step)
 
 
 def vdot(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -76,63 +81,94 @@ def _resolve_dots(dot, dot2):
     return dot, dot2
 
 
-@named_scope("irls.pcg")
 def pcg(matvec: Callable[[jax.Array], jax.Array],
         b: jax.Array,
         x0: Optional[jax.Array] = None,
         precond: Optional[Callable[[jax.Array], jax.Array]] = None,
         tol: float = 1e-3,
         max_iters: int = 300,
-        record_history: bool = False) -> PCGResult:
+        record_history: bool = False,
+        make_precond: Optional[Callable[[], Optional[Callable]]] = None
+        ) -> PCGResult:
     """Solve ``A x = b`` with A SPD given through ``matvec``.
 
-    ``precond`` applies M⁻¹ (identity when None).  ``x0`` enables warm starts.
-    ``tol`` may be a traced scalar (adaptive inner tolerances).
+    ``precond`` applies M⁻¹ (identity when None).  ``make_precond`` is the
+    lazy alternative: a zero-argument builder returning that apply (or
+    None), called only when the initial residual misses the tolerance —
+    an ``x0`` that already meets it is returned as is, and the builder's
+    work (a block factorization, say) never runs.  ``x0`` enables warm
+    starts.  ``tol`` may be a traced scalar (adaptive inner tolerances).
+
+    The gate is a ``lax.cond`` on the initial residual, so do not
+    ``jax.vmap`` this solver: a batched predicate turns the cond into a
+    select that runs both branches.  ``pcg_masked`` is the batched form.
+    ``PCGResult.factored`` says which branch ran.
     """
-    if precond is None:
-        precond = lambda r: r
+    if precond is not None and make_precond is not None:
+        raise ValueError("pass precond or make_precond, not both")
+    if make_precond is None:
+        make_precond = lambda: precond
     x = jnp.zeros_like(b) if x0 is None else x0
 
-    bb = vdot(b, b)
-    # guard: b == 0 ⇒ x = 0 is exact; avoid dividing by zero
-    bb = jnp.where(bb > 0, bb, 1.0)
-    tol2 = jnp.asarray(tol, b.dtype) ** 2 * bb
+    with jax.named_scope("irls.pcg"):
+        bb = vdot(b, b)
+        # guard: b == 0 ⇒ x = 0 is exact; avoid dividing by zero
+        bb = jnp.where(bb > 0, bb, 1.0)
+        tol2 = jnp.asarray(tol, b.dtype) ** 2 * bb
 
-    r = b - matvec(x)
-    z = precond(r)
-    p = z
-    rz = vdot(r, z)
-    rr = vdot(r, r)
+        r = b - matvec(x)
+        rr = vdot(r, r)
 
-    hist_len = max_iters + 1 if record_history else 1
-    history = jnp.full((hist_len,), jnp.nan, dtype=b.dtype)
-    history = history.at[0].set(jnp.sqrt(rr / bb))
+        hist_len = max_iters + 1 if record_history else 1
+        history = jnp.full((hist_len,), jnp.nan, dtype=b.dtype)
+        history = history.at[0].set(jnp.sqrt(rr / bb))
+        factored = jnp.logical_and(rr > tol2, max_iters > 0)
 
     def cond(state):
         _, _, _, _, rr, it, _ = state
         return jnp.logical_and(rr > tol2, it < max_iters)
 
-    def body(state):
-        x, r, p, rz, rr, it, hist = state
-        Ap = matvec(p)
-        pAp = vdot(p, Ap)
-        alpha = rz / jnp.where(pAp != 0, pAp, 1.0)
-        x = x + alpha * p
-        r = r - alpha * Ap
-        z = precond(r)
-        rz_new = vdot(r, z)
-        beta = rz_new / jnp.where(rz != 0, rz, 1.0)
-        p = z + beta * p
-        rr = vdot(r, r)
-        it = it + 1
-        if record_history:
-            hist = hist.at[it].set(jnp.sqrt(rr / bb))
-        return x, r, p, rz_new, rr, it, hist
+    def solve(_):
+        # the builder runs inside the branch, under its own scope
+        # (``irls.factor`` for the IRLS drivers' preconditioners)
+        apply_M = make_precond()
+        if apply_M is None:
+            apply_M = lambda r: r
 
-    state = (x, r, p, rz, rr, jnp.asarray(0, jnp.int32), history)
-    x, r, p, rz, rr, it, history = jax.lax.while_loop(cond, body, state)
-    return PCGResult(x=x, iters=it, rel_res=jnp.sqrt(rr / bb),
-                     history=history)
+        def body(state):
+            x, r, p, rz, rr, it, hist = state
+            Ap = matvec(p)
+            pAp = vdot(p, Ap)
+            alpha = rz / jnp.where(pAp != 0, pAp, 1.0)
+            x = x + alpha * p
+            r = r - alpha * Ap
+            z = apply_M(r)
+            rz_new = vdot(r, z)
+            beta = rz_new / jnp.where(rz != 0, rz, 1.0)
+            p = z + beta * p
+            rr = vdot(r, r)
+            it = it + 1
+            if record_history:
+                hist = hist.at[it].set(jnp.sqrt(rr / bb))
+            return x, r, p, rz_new, rr, it, hist
+
+        with jax.named_scope("irls.pcg"):
+            z = apply_M(r)
+            state = (x, r, z, vdot(r, z), rr, jnp.asarray(0, jnp.int32),
+                     history)
+            x_, _, _, _, rr_, it, hist = jax.lax.while_loop(cond, body,
+                                                             state)
+        return x_, it, rr_, hist
+
+    def skip(_):
+        # what the loop returns when its first test fails: x0, untouched
+        return x, jnp.asarray(0, jnp.int32), rr, history
+
+    x, it, rr, history = jax.lax.cond(factored, solve, skip, None)
+    with jax.named_scope("irls.pcg"):
+        rel_res = jnp.sqrt(rr / bb)
+    return PCGResult(x=x, iters=it, rel_res=rel_res, history=history,
+                     factored=factored)
 
 
 @named_scope("irls.pcg")

@@ -2,6 +2,7 @@
 compiled programs' metadata, and the host spans of the IRLS loop, the
 two-level rounding and ``Problem.build``."""
 import os
+import re
 import sys
 
 import jax
@@ -60,6 +61,65 @@ def test_host_step_carries_every_phase(instance, first):
     assert _scopes(hlo) == set(PHASES)
 
 
+def _computations(hlo: str):
+    """``{name: instruction lines}`` of an HLO module's text, and the
+    entry computation's name."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(ENTRY )?%(\S+) \(.*\{$", line)
+        if m:
+            cur = m.group(2)
+            comps[cur] = []
+            entry = cur if m.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    return comps, entry
+
+
+def _reachable(comps, roots, into_conditionals: bool):
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            if not into_conditionals and " conditional(" in line:
+                continue
+            todo += [c for c in re.findall(r"%([\w.\-]+)", line)
+                     if c in comps]
+    return seen
+
+
+@pytest.mark.parametrize("first", [False, True])
+def test_host_step_gates_the_factorization(instance, first):
+    """The preconditioner's construction is traced inside the branch of a
+    ``conditional`` on the initial residual: gated, not hoisted out."""
+    from repro.core.irls import _Stepper
+    sess = _session(instance)
+    block_plan, ell_plan = sess._plans_for(sess.cfg)
+    st = _Stepper(sess.problem.device_graph(jnp.float32), sess.cfg,
+                  block_plan, ell_plan)
+    g = st.g
+    hlo = st._jit_step.lower(jnp.zeros_like(g.c_s), 1e-6, 1e-3, g.c, g.c_s,
+                             g.c_t, None, first=first).compile().as_text()
+    comps, entry = _computations(hlo)
+    conds = [line for line in comps[entry] if " conditional(" in line]
+    assert len(conds) == 1
+    branches = re.search(r"branch_computations=\{([^}]*)\}", conds[0])
+    branches = [b.strip().lstrip("%") for b in branches.group(1).split(",")]
+    inside = _reachable(comps, branches, into_conditionals=True)
+    outside = _reachable(comps, [entry], into_conditionals=False)
+    factor = lambda names: [line for c in names for line in comps[c]
+                            if "/irls.factor/" in line]
+    assert factor(inside)
+    assert not factor(outside)
+    assert any("Cholesky" in line or "potrf" in line
+               for c in inside for line in comps[c])
+
+
 @pytest.mark.parametrize("layout", ["coo", "ell"])
 def test_scanned_programs_carry_every_phase(instance, layout):
     """The adaptive schedule reads the fractional cut every iteration; the
@@ -88,6 +148,8 @@ def test_host_loop_spans_per_iteration(instance, traced):
     dispatch = spans["session.irls.dispatch"]
     assert [s.attrs["l"] for s in dispatch] == list(range(iters))
     assert len(spans["session.irls.readback"]) == iters
+    assert ([s.attrs["factored"] for s in spans["session.irls.readback"]]
+            == [int(b) for b in res.diagnostics.precond_built])
     irls = spans["session.irls"][0]
     for s in dispatch + spans["session.irls.readback"]:
         assert s.parent_id == irls.span_id

@@ -165,3 +165,67 @@ def test_pcg_masked_inf_tol_is_noop():
     res = pcg_masked(lambda x: A @ x, b, x0=x0, tol=jnp.inf, max_iters=50)
     assert int(res.iters) == 0
     np.testing.assert_array_equal(np.asarray(res.x), np.asarray(x0))
+
+
+# ---------------------------------------------------------------------------
+# lazy preconditioner builder (the host IRLS stepper's form)
+# ---------------------------------------------------------------------------
+
+def _nan_builder():
+    """A builder whose apply poisons every step: any use shows as NaN."""
+    return lambda r: r * jnp.nan
+
+
+@pytest.mark.parametrize("jit", [False, True])
+def test_pcg_builder_skips_when_warm_start_meets_tol(jit):
+    A = jnp.asarray(_spd(40, 13), jnp.float32)
+    x_true = jnp.asarray(np.random.default_rng(2).standard_normal(40),
+                         jnp.float32)
+    b = A @ x_true
+    x0 = x_true + 1e-6 * jnp.asarray(
+        np.random.default_rng(3).standard_normal(40), jnp.float32)
+    mv = lambda x: A @ x
+    lazy = lambda x0: pcg(mv, b, x0=x0, tol=1e-3, max_iters=50,
+                          record_history=True, make_precond=_nan_builder)
+    eager = lambda x0: pcg(mv, b, x0=x0, tol=1e-3, max_iters=50,
+                           record_history=True,
+                           precond=lambda r: r / jnp.diag(A))
+    if jit:
+        lazy, eager = jax.jit(lazy), jax.jit(eager)
+    r1, r2 = lazy(x0), eager(x0)
+    assert not bool(r1.factored) and not bool(r2.factored)
+    assert int(r1.iters) == 0 == int(r2.iters)
+    np.testing.assert_array_equal(np.asarray(r1.x), np.asarray(x0))
+    assert float(r1.rel_res) == float(r2.rel_res) <= 1e-3
+    np.testing.assert_array_equal(np.asarray(r1.history),
+                                  np.asarray(r2.history))
+
+
+@pytest.mark.parametrize("with_x0", [False, True])
+def test_pcg_builder_matches_apply_form(with_x0):
+    A = jnp.asarray(_spd(50, 17), jnp.float32)
+    b = jnp.asarray(np.random.default_rng(8).standard_normal(50), jnp.float32)
+    x0 = (jnp.asarray(np.random.default_rng(9).standard_normal(50),
+                      jnp.float32) if with_x0 else None)
+    apply_M = lambda r: r / jnp.diag(A)
+    calls = []
+
+    def build():
+        calls.append(1)
+        return apply_M
+
+    kw = dict(x0=x0, tol=1e-5, max_iters=500, record_history=True)
+    lazy = pcg(lambda x: A @ x, b, make_precond=build, **kw)
+    eager = pcg(lambda x: A @ x, b, precond=apply_M, **kw)
+    assert calls == [1]
+    assert bool(lazy.factored) and int(lazy.iters) > 0
+    assert int(lazy.iters) == int(eager.iters)
+    np.testing.assert_allclose(lazy.x, eager.x, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(lazy.rel_res, eager.rel_res, rtol=1e-6)
+
+
+def test_pcg_rejects_both_preconditioner_forms():
+    A = jnp.eye(4)
+    with pytest.raises(ValueError, match="not both"):
+        pcg(lambda x: A @ x, jnp.ones(4), precond=lambda r: r,
+            make_precond=lambda: None)

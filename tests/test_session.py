@@ -64,6 +64,34 @@ def test_session_backends_match_legacy_solve_road(road_instance):
         assert res.cut_value == pytest.approx(cut_ref, rel=1e-4), backend
 
 
+def test_host_solve_skips_factorization_when_warm_start_meets_tol(
+        road_instance, monkeypatch):
+    """The host stepper builds the block-Jacobi preconditioner only in the
+    IRLS iterations whose warm start misses ``pcg_tol``; the cut is that of
+    building it every iteration (the apply form of ``pcg``)."""
+    from repro.core import irls
+    from repro.core.pcg import pcg as pcg_fn
+    cfg = IRLSConfig(n_irls=15, n_blocks=4, pcg_max_iters=80, pcg_tol=1e-3)
+    res = MinCutSession(road_instance, cfg).solve()
+    diag = res.diagnostics
+    assert len(diag.precond_built) == len(diag.pcg_iters) == cfg.n_irls + 1
+    # built exactly where PCG stepped: the cold solve plus the warm
+    # iterations whose x0 missed the tolerance
+    assert diag.precond_built == [k > 0 for k in diag.pcg_iters]
+    assert diag.precond_built[0]
+    assert diag.precond_builds == 1 + sum(k > 0 for k in diag.pcg_iters[1:])
+    assert diag.precond_builds < cfg.n_irls + 1
+
+    def eager(*a, make_precond, **kw):
+        return pcg_fn(*a, precond=make_precond(), **kw)
+
+    monkeypatch.setattr(irls, "pcg", eager)
+    ref = MinCutSession(road_instance, cfg).solve()
+    assert ref.diagnostics.pcg_iters == diag.pcg_iters
+    assert res.cut_value == ref.cut_value
+    np.testing.assert_array_equal(res.cut.in_source, ref.cut.in_source)
+
+
 def test_pirmcut_wrapper_matches_session(grid_instance):
     res, v, diag = pirmcut(grid_instance, CFG)
     sess_res = MinCutSession(grid_instance, CFG).solve()

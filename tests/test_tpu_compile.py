@@ -89,3 +89,24 @@ def test_smoke_scanned_program_compiles_for_v5e(one_chip, monkeypatch):
     abstract = [_spec(one_chip, a.shape, a.dtype) for a in args]
     text = run.lower(*abstract).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_host_step_compiles_for_v5e(one_chip):
+    """The host IRLS step the benchmark cells run (COO layout, dense block
+    Jacobi): its block factorization sits in a conditional branch, gated on
+    the warm start's residual, and the TPU compiler keeps it there."""
+    from repro.core import IRLSConfig, MinCutSession
+    from repro.core.irls import _Stepper
+    from repro.launch.solve import build_instance
+
+    cfg = IRLSConfig(n_blocks=8)
+    sess = MinCutSession(build_instance("road", 40, 0), cfg, profile=False)
+    block_plan, ell_plan = sess._plans_for(cfg)
+    st = _Stepper(sess.problem.device_graph(jnp.float32), cfg, block_plan,
+                  ell_plan)
+    g = st.g
+    s = lambda a: _spec(one_chip, jnp.shape(a), jnp.result_type(a))
+    text = st._jit_step.lower(s(g.c_s), s(1e-6), s(1e-3), s(g.c), s(g.c_s),
+                              s(g.c_t), None, first=False).compile().as_text()
+    assert " conditional(" in text
+    assert 'custom_call_target="Cholesky"' in text
