@@ -64,7 +64,8 @@ def reference_control(cell, seed: int) -> dict:
                         low.in_source,
                         cut_value(inst.src, inst.dst, lw, ls, lt,
                                   low.in_source)))
-    return compare.compare(ref, answers)
+    # the reference answers every request it is given
+    return compare.compare(ref, answers, 0)
 
 
 def program_control(cell, seed: int, seconds: float, devices) -> dict:

@@ -1,15 +1,19 @@
 """The one traffic generator: every mix is a data file,
 ``bench/traffic/<mix>.json``, read by :func:`load`.
 
-A mix drives back-to-back ``MinCutSession.solve`` calls on the topology of
-the cell's configuration, through the session backend ``backend``.  Each
-solve is a fresh instance of the configuration's family, solved cold, as
-the paper's Table 3 times its solves: the terminals are drawn anew
-(``instances.draw``), the edge weights are the configuration's.  ``pool``
-instances are drawn in set-up from ``(seed, k)``, so one seed gives the
-same instances in every run; the window takes them in turn, and from the
-first again once it has taken them all.  One more, ``k = pool``, warms
-the programs up.
+A mix drives the topology of the cell's configuration through the
+session backend ``backend``, by the entry ``entry`` names
+(``bench/harness.py``): ``session`` (the default), back-to-back
+``MinCutSession.solve`` calls, or ``server``, ``clients`` closed-loop
+clients of ``MinCutServer.submit``, with the server settings the file
+names.  Each request is a fresh instance of the configuration's family,
+as the paper's Table 3 times its solves: the terminals are drawn anew
+(``instances.draw``), the edge weights are the configuration's.  It is
+solved cold, unless the mix has ``tenants``: then each client is a tenant
+and warm-starts from its last answer.  ``pool`` instances are drawn in
+set-up from ``(seed, k)``, so one seed gives the same instances in every
+run; the window takes them in turn, and from the first again once it has
+taken them all.  One more, ``k = pool``, warms the programs up.
 """
 from __future__ import annotations
 

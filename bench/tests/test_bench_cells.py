@@ -108,3 +108,79 @@ def test_new_cell_needs_only_new_files(tmp_path, monkeypatch):
                            root=root, trace_dir=str(tmp_path / "tr"))
     assert out["metrics"]["solves_in_window"]["value"] >= 1
     assert list(out)[-1] == "checks"
+
+
+# the server's own deployment: its default solver (point Jacobi, adaptive
+# early exit) on the scanned backend, where each batch bucket and the warm
+# start compile programs of their own; and the host backend with tenants.
+# The third item says whether the program serves exact cuts there: on the
+# scanned backend a tenant's warm-started requests do not (PERF.md, open
+# question 1), so that case checks only what the harness guarantees.
+SERVER_DEFAULTS = {"n_irls": 20, "n_blocks": 1, "precond": "jacobi",
+                   "irls_tol": 1e-3, "adaptive_tol": True}
+SERVER_MIXES = {
+    "scanned_tenants": ({"backend": "scanned", "clients": 2, "pool": 3,
+                         "tenants": True}, SERVER_DEFAULTS, False),
+    "scanned_batches": ({"backend": "scanned", "clients": 4, "pool": 3,
+                         "n_workers": 1, "max_batch": 4,
+                         "flush_policy": "deadline", "max_wait_ms": 50.0},
+                        SERVER_DEFAULTS, True),
+    "host_tenants": ({"backend": "host", "clients": 2, "pool": 2,
+                      "tenants": True, "n_workers": 1, "max_batch": 2,
+                      "flush_policy": "deadline"}, {"precond": "jacobi"},
+                     True),
+}
+
+
+@pytest.mark.parametrize("mix", sorted(SERVER_MIXES))
+def test_new_server_cell_needs_only_new_files(tmp_path, monkeypatch,
+                                              capsys, mix):
+    """A second cell on the server entry, with a configuration, server
+    settings and tenants of its own: new files, and its name added to the
+    served metrics.  Set-up runs every program the window uses."""
+    traffic, irls, exact = SERVER_MIXES[mix]
+    root = tiny_root(tmp_path)
+    bdir = os.path.join(root, "bench")
+    with open(os.path.join(bdir, "configs", "road_ny.json")) as f:
+        cfg = json.load(f)
+    cfg["solver"]["irls"].update(irls)
+    with open(os.path.join(bdir, "configs", "road_j.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(bdir, "traffic", "serve_pair.json"), "w") as f:
+        json.dump({"entry": "server", **traffic}, f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "road_j", "source": "x",
+                             "file": "bench/configs/road_j.json",
+                             "reduced": ["n", "m"], "why": "test"})
+    bench["workloads"].append({"name": "road_j.serve_pair",
+                               "config": "road_j", "traffic": "serve_pair",
+                               "chips": 1, "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in ("served_per_s", "latency_p50_ms",
+                         "queue_wait_p50_ms.road_ny_serve"):
+            m["workloads"].append("road_j.serve_pair")
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    cell = harness.load_cell("road_j.serve_pair", root=root)
+    dev = jax.devices()[:1]
+    out = harness.run_cell(cell, 2**33 + 3, 1.0, False, dev, harness.clock(),
+                           root=root)
+    assert out["correct"] or not exact, out["checks"]
+    assert out["attempted"] >= 2 and out["failed"] == 0
+    assert out["window_compiles"] == 0
+    record = json.loads(capsys.readouterr().err.split("bench: ")[-1])
+    assert record["setup"]["problem_build_s"] > 0
+    if traffic.get("n_workers") == 1 and not traffic.get("tenants"):
+        assert record["serve"]["max_batch_size"] >= 2
+    assert set(out["metrics"]) == {"setup_s", "served_per_s",
+                                   "latency_p50_ms"}
+    assert all(v["value"] > 0 for v in out["metrics"].values())
+    monkeypatch.setattr(harness, "_xplane", lambda d: d)
+    monkeypatch.setattr(harness.trace_reduce, "load", lambda p: None)
+    monkeypatch.setattr(harness.trace_reduce, "reduce",
+                        lambda pd: fake_trace())
+    out = harness.run_cell(cell, 2**33 + 3, 0.5, True, dev, harness.clock(),
+                           root=root, trace_dir=str(tmp_path / "tr"))
+    assert set(out["metrics"]) == {"queue_wait_p50_ms.road_ny_serve"}
+    assert out["window_compiles"] == 0

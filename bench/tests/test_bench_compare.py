@@ -42,7 +42,7 @@ def test_exact_cut_passes_and_spoiled_cuts_fail(family, side):
     inst = _inst(family, side)
     ref = Reference(inst.n, inst.src, inst.dst)
     exact = ref.solve(inst.weight, inst.s_weight, inst.t_weight).in_source
-    ok, _ = compare.judge(compare.compare(ref, [_answer(inst, exact)]))
+    ok, _ = compare.judge(compare.compare(ref, [_answer(inst, exact)], 0))
     assert ok
     flipped = exact.copy()
     flipped[np.argmax(inst.s_weight + inst.t_weight)] ^= True
@@ -51,7 +51,7 @@ def test_exact_cut_passes_and_spoiled_cuts_fail(family, side):
                     inst.src, inst.dst, inst.weight, inst.s_weight,
                     inst.t_weight, exact)),
                 _answer(inst, exact[:-1], value=1.0)):
-        ok, checks = compare.judge(compare.compare(ref, [ans]))
+        ok, checks = compare.judge(compare.compare(ref, [ans], 0))
         assert not ok, checks
 
 
@@ -65,6 +65,12 @@ def test_bfloat16_reference_in_place_is_not_correct(family, side):
     side_low = ref.solve(*low).in_source
     numbers = compare.compare(ref, [(
         (inst.weight, inst.s_weight, inst.t_weight), side_low,
-        cut_value(inst.src, inst.dst, *low, side_low))])
+        cut_value(inst.src, inst.dst, *low, side_low))], 0)
     ok, checks = compare.judge(numbers)
     assert not ok, checks
+
+
+def test_judge_refuses_a_missing_number():
+    """A number that the caller leaves out fails loudly, not as correct."""
+    with pytest.raises(KeyError):
+        compare.judge({"cut_gap": 0.0})

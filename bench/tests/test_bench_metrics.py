@@ -24,12 +24,22 @@ def _solve_run():
         trace=fake_trace(busy_s=4.0, window_s=5.0, collective_s=0.4))
 
 
+def _serve_run():
+    from repro.serve import ServeMetrics
+
+    serve = ServeMetrics()
+    for q in (0.001, 0.003, 0.002):
+        serve.record_request({"queue": q, "total": 2.0}, 0.0)
+    return harness.Run(solves=_solve_run().solves, serve=serve)
+
+
 EXPECTED = {
     "problem_build_s": (_solve_run, 9.5),
     "rounding_ms": (_solve_run, 60.0),
     "pcg_iters": (_solve_run, 20.0),
     "irls_step_ms": (_solve_run, 50.0),
     "device_idle_share": (_solve_run, 20.0),
+    "queue_wait_p50_ms": (_serve_run, 2.0),
 }
 
 
